@@ -172,6 +172,8 @@ def main(argv=None) -> None:
                          "this port while the bench runs (0 = "
                          "ephemeral; the URL is printed)")
     args = ap.parse_args(argv)
+    from repro.core.compile_cache import enable_compile_cache
+    enable_compile_cache()
     server = None
     if args.serve is not None:
         from repro.obs.serve import ObsServer

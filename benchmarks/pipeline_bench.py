@@ -565,6 +565,8 @@ def main(argv=None) -> None:
                          "this port while the bench runs (0 = "
                          "ephemeral; the URL is printed)")
     args = ap.parse_args(argv)
+    from repro.core.compile_cache import enable_compile_cache
+    enable_compile_cache()
     # default=None keeps an explicit `--out <default path>` detectable
     out = args.out if args.out is not None else \
         (None if args.smoke else DEFAULT_OUT)

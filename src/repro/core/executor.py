@@ -61,6 +61,7 @@ arithmetic is exact even when decode overlaps compute.
 """
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
@@ -750,6 +751,16 @@ class TrackBroker:
         return K
 
 
+def _auto_axes(mesh):
+    """The same devices and axis names with every axis Auto.  A chunk
+    is placed on the mesh and XLA partitions the stages from there;
+    Explicit axes (``jax.make_mesh``'s default) would instead demand an
+    output sharding from every gather in the DETECT stage."""
+    from jax.sharding import AxisType, Mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 class _RunContext:
     """Per-clip derived state shared by every stage.
 
@@ -800,7 +811,7 @@ class _RunContext:
         self.sharding = None
         if options.mesh is not None:
             from repro.distributed.sharding import LogicalRules
-            rules = LogicalRules(options.mesh)
+            rules = LogicalRules(_auto_axes(options.mesh))
             self.sharding = rules.named_sharding(
                 (self.chunk, self.H, self.W, 3),
                 ("batch", None, None, None))
@@ -877,6 +888,15 @@ class _RunContext:
         return self.devices[(self.device_offset + task.index)
                             % len(self.devices)]
 
+    def placed(self, task: ChunkTask):
+        """Make the chunk's round-robin device the default for its
+        PROXY and DETECT dispatches, so host inputs (proxy frames,
+        full-frame detector batches) land where its uploaded buffer
+        does.  A mesh places by sharding instead."""
+        if self.sharding is not None:
+            return contextlib.nullcontext()
+        return jax.default_device(self.device_for(task))
+
     def upload(self, task: ChunkTask):
         """Pad the chunk to B frames (one gather jit shape) and place it
         on this chunk's device / mesh sharding."""
@@ -923,113 +943,112 @@ def stage_proxy(ctx: _RunContext, task: ChunkTask) -> ChunkTask:
     ``plan_from_mapped`` takes exact shortcuts on the stats.  The
     legacy path (``fused_plan=False``) pulls the score map back and
     maps/plans fully on the host; both produce bit-identical plans."""
-    if ctx.proxy is not None:
-        ctx.profile.dispatch("proxy")
-        pframes = downsample_chunk(task.frames, ctx.proxy.resolution)
-        if ctx.fused_plan:
-            grids, stats = ctx.proxy.plan_batch(
-                pframes, ctx.params.proxy_threshold, ctx.grid)
-            task.plan = plan_from_mapped(grids, stats, ctx.sizeset,
-                                         ctx.cfg.windows.max_windows,
-                                         chunk_size=ctx.chunk)
+    with ctx.placed(task):
+        if ctx.proxy is not None:
+            ctx.profile.dispatch("proxy")
+            pframes = downsample_chunk(task.frames, ctx.proxy.resolution)
+            if ctx.fused_plan:
+                grids, stats = ctx.proxy.plan_batch(
+                    pframes, ctx.params.proxy_threshold, ctx.grid)
+                task.plan = plan_from_mapped(grids, stats, ctx.sizeset,
+                                             ctx.cfg.windows.max_windows,
+                                             chunk_size=ctx.chunk)
+            else:
+                _, pos = ctx.proxy.scores_batch(pframes,
+                                                ctx.params.proxy_threshold)
+                grids = [map_proxy_grid(p, ctx.grid) for p in pos]
+                task.plan = plan_chunk(grids, ctx.sizeset,
+                                       ctx.cfg.windows.max_windows,
+                                       chunk_size=ctx.chunk)
+            if ctx.proxy_fracs is not None:
+                # drift signal: positive-cell fraction per REAL frame (an
+                # observer of grids the plan already computed — rows past
+                # the chunk's frame count are padding)
+                g = np.asarray(grids)[:len(task.frame_ids)]
+                fracs = (g > 0).mean(axis=tuple(range(1, g.ndim)))
+                ctx.proxy_fracs.extend(float(v) for v in fracs)
         else:
-            _, pos = ctx.proxy.scores_batch(pframes,
-                                            ctx.params.proxy_threshold)
-            grids = [map_proxy_grid(p, ctx.grid) for p in pos]
-            task.plan = plan_chunk(grids, ctx.sizeset,
-                                   ctx.cfg.windows.max_windows,
-                                   chunk_size=ctx.chunk)
-        if ctx.proxy_fracs is not None:
-            # drift signal: positive-cell fraction per REAL frame (an
-            # observer of grids the plan already computed — rows past
-            # the chunk's frame count are padding)
-            g = np.asarray(grids)[:len(task.frame_ids)]
-            fracs = (g > 0).mean(axis=tuple(range(1, g.ndim)))
-            ctx.proxy_fracs.extend(float(v) for v in fracs)
-    else:
-        task.plan = full_frame_plan(len(task.frame_ids), ctx.sizeset)
-    return task
+            task.plan = full_frame_plan(len(task.frame_ids), ctx.sizeset)
+        return task
 
 
 def stage_detect(ctx: _RunContext, task: ChunkTask) -> ChunkTask:
     """Cross-frame bucketed detection; reassemble per-frame detections
     in the exact order the per-frame path would have produced them."""
-    detector = ctx.detector
-    W, H = ctx.W, ctx.H
-    plan, frames = task.plan, task.frames
-    frames_dev = task.frames_dev
-    per_window: Dict[Tuple[int, int], np.ndarray] = {}
-    for size, entries in plan.by_size.items():
-        pw, ph = size[0] * CELL_PX, size[1] * CELL_PX
-        n = len(entries)
-        origins = [(x * CELL_PX / W, y * CELL_PX / H)
-                   for (_, x, y, _) in entries]
-        scales = [(pw / W, ph / H)] * n
-        broker = ctx.broker()
-        ctx.profile.dispatch("detect")
-        if (pw, ph) == (W, H):
-            # full-frame windows: the crop is the frame itself
-            stack = frames[[slot for (slot, _, _, _) in entries]]
-            if broker is not None:
-                dets = broker.detect(detector, stack,
-                                     ctx.params.det_conf,
-                                     origins, scales, n)
+    with ctx.placed(task):
+        detector = ctx.detector
+        W, H = ctx.W, ctx.H
+        plan, frames = task.plan, task.frames
+        frames_dev = task.frames_dev
+        per_window: Dict[Tuple[int, int], np.ndarray] = {}
+        for size, entries in plan.by_size.items():
+            pw, ph = size[0] * CELL_PX, size[1] * CELL_PX
+            n = len(entries)
+            origins = [(x * CELL_PX / W, y * CELL_PX / H)
+                       for (_, x, y, _) in entries]
+            scales = [(pw / W, ph / H)] * n
+            broker = ctx.broker()
+            ctx.profile.dispatch("detect")
+            if (pw, ph) == (W, H):
+                # full-frame windows: the crop is the frame itself
+                stack = frames[[slot for (slot, _, _, _) in entries]]
+                if broker is not None:
+                    dets = broker.detect(detector, stack,
+                                         ctx.params.det_conf,
+                                         origins, scales, n)
+                else:
+                    dets = detector.detect_batch_bucketed(
+                        stack, ctx.params.det_conf, origins=origins,
+                        scales=scales)
             else:
-                dets = detector.detect_batch_bucketed(
-                    stack, ctx.params.det_conf, origins=origins,
-                    scales=scales)
-        else:
-            if frames_dev is None:       # lazy path (no double buffer)
-                frames_dev = ctx.upload(task)
-            tbl = np.zeros((next_bucket(n), 3), np.int32)
-            for k, (slot, x, y, _) in enumerate(entries):
-                tbl[k] = (slot, y, x)
-            from repro.kernels.window_gather import window_gather_batch
-            crops = window_gather_batch(frames_dev, tbl,
-                                        win_h=ph, win_w=pw, cell=CELL_PX)
-            # crops stay device-side: detect_batch feeds them straight
-            # into the detector without a host round-trip
-            if broker is not None:
-                dets = broker.detect(detector, crops,
-                                     ctx.params.det_conf,
-                                     origins, scales, n)
-            else:
-                dets = detector.detect_batch(
-                    crops, ctx.params.det_conf, origins=origins,
-                    scales=scales, n_valid=n)
-        for (slot, _, _, wi), d in zip(entries, dets):
-            per_window[(slot, wi)] = d
+                if frames_dev is None:       # lazy path (no double buffer)
+                    frames_dev = ctx.upload(task)
+                tbl = np.zeros((next_bucket(n), 3), np.int32)
+                for k, (slot, x, y, _) in enumerate(entries):
+                    tbl[k] = (slot, y, x)
+                from repro.kernels.window_gather import window_gather_batch
+                crops = window_gather_batch(frames_dev, tbl,
+                                            win_h=ph, win_w=pw, cell=CELL_PX)
+                # crops stay device-side: detect_batch feeds them straight
+                # into the detector without a host round-trip
+                if broker is not None:
+                    dets = broker.detect(detector, crops,
+                                         ctx.params.det_conf,
+                                         origins, scales, n)
+                else:
+                    dets = detector.detect_batch(
+                        crops, ctx.params.det_conf, origins=origins,
+                        scales=scales, n_valid=n)
+            for (slot, _, _, wi), d in zip(entries, dets):
+                per_window[(slot, wi)] = d
 
-    merged: List[np.ndarray] = []
-    for slot, wins in enumerate(plan.windows):
-        if not wins:
-            merged.append(np.zeros((0, 5), np.float32))
-        elif len(wins) == 1 and wins[0][2] == ctx.sizeset.full:
-            # the per-frame fast path applies no cross-window NMS
-            merged.append(per_window[(slot, 0)])
-        else:
-            by_size_frame: Dict[Tuple[int, int], List[int]] = {}
-            for wi, (_, _, s) in enumerate(wins):
-                by_size_frame.setdefault(s, []).append(wi)
-            parts = [per_window[(slot, wi)]
-                     for wis in by_size_frame.values() for wi in wis]
-            merged.append(nms(np.concatenate(parts)))
-    task.dets = merged
-    # steer the decode worker's eager upload (a stale read just means
-    # one lazy upload): this chunk gathered iff any size class was
-    # sub-frame
-    ctx.prev_chunk_gathered = any(
-        (s[0] * CELL_PX, s[1] * CELL_PX) != (W, H)
-        for s in plan.by_size)
-    # donate the device buffer back: DETECT is its last consumer, and
-    # freeing it here bounds in-flight device memory to prefetch_depth
-    if frames_dev is not None:
-        task.frames_dev = None
-        try:
+        merged: List[np.ndarray] = []
+        for slot, wins in enumerate(plan.windows):
+            if not wins:
+                merged.append(np.zeros((0, 5), np.float32))
+            elif len(wins) == 1 and wins[0][2] == ctx.sizeset.full:
+                # the per-frame fast path applies no cross-window NMS
+                merged.append(per_window[(slot, 0)])
+            else:
+                by_size_frame: Dict[Tuple[int, int], List[int]] = {}
+                for wi, (_, _, s) in enumerate(wins):
+                    by_size_frame.setdefault(s, []).append(wi)
+                parts = [per_window[(slot, wi)]
+                         for wis in by_size_frame.values() for wi in wis]
+                merged.append(nms(np.concatenate(parts)))
+        task.dets = merged
+        # steer the decode worker's eager upload (a stale read just means
+        # one lazy upload): this chunk gathered iff any size class was
+        # sub-frame
+        ctx.prev_chunk_gathered = any(
+            (s[0] * CELL_PX, s[1] * CELL_PX) != (W, H)
+            for s in plan.by_size)
+        # donate the device buffer back: DETECT is its last consumer, and
+        # freeing it here bounds in-flight device memory to prefetch_depth
+        if frames_dev is not None:
+            task.frames_dev = None
             frames_dev.delete()
-        except Exception:
-            pass
-    return task
+        return task
 
 
 def stage_track(ctx: _RunContext, task: ChunkTask) -> ChunkTask:
@@ -1424,6 +1443,15 @@ class ClipExecutor:
         self.bank = bank
         self.params = params
         self.options = options or ExecutorOptions()
+        from repro.kernels import use_pallas
+        if self.options.mesh is not None and use_pallas():
+            raise NotImplementedError(
+                "ExecutorOptions.mesh cannot run where window gathers are "
+                "Pallas kernels (a TPU): Mosaic kernels are not "
+                "partitioned automatically, and window_gather_batch has "
+                "no shard_map over the chunk's batch axis with a "
+                "per-shard window table.  Leave mesh unset to "
+                "round-robin whole chunks over ExecutorOptions.devices.")
         self.stages = dict(DEFAULT_STAGES)
         if stages:
             self.stages.update(stages)

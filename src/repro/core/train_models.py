@@ -44,7 +44,10 @@ def train_detector(arch: str, clips: Sequence[Clip],
                    resolutions: Sequence[Tuple[int, int]],
                    steps: int = 240, batch: int = 8, seed: int = 0,
                    lr: float = 3e-3) -> det_mod.Detector:
-    """Multi-resolution detector pre-training on synthetic GT boxes."""
+    """Multi-resolution detector pre-training on synthetic GT boxes.
+    Frames come through the pipeline's render cache, so a step renders
+    only frames no earlier step drew."""
+    from repro.core.pipeline import render_frame
     params = det_mod.init_detector(arch, seed)
     rng = np.random.default_rng(seed)
     S = det_mod.STRIDE
@@ -57,7 +60,7 @@ def train_detector(arch: str, clips: Sequence[Clip],
             for _ in range(batch):
                 clip = clips[rng.integers(len(clips))]
                 f = int(rng.integers(clip.n_frames))
-                frames.append(clip.render(f, W, H))
+                frames.append(render_frame(clip, f, W, H)[0])
                 boxes.append(clip.boxes_at(f))
             obj, box = det_mod.make_targets(boxes, hc, wc)
             yield (jnp.asarray(np.stack(frames)), jnp.asarray(obj),

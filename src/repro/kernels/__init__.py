@@ -7,7 +7,10 @@ Each kernel subpackage follows the same layout:
               grid axes for accumulation).
   ops.py    — the public jit'd wrapper.  Dispatches to the Pallas kernel
               on TPU and to a memory-equivalent pure-jnp implementation on
-              CPU (this container), so models lower identically everywhere.
+              CPU, so models lower identically everywhere.  An op whose
+              kernel body cannot be lowered for the TPU says so in a named
+              ``TPU_PALLAS = False`` constant and runs its XLA
+              implementation there.
   ref.py    — the pure-jnp oracle used by tests (``interpret=True`` runs
               the kernel body on CPU and is asserted allclose against it).
 
@@ -43,9 +46,24 @@ def set_kernel_mode(mode) -> None:
     _FORCE["mode"] = mode
 
 
-def use_pallas() -> bool:
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def use_pallas(tpu_pallas: bool = True) -> bool:
+    """Whether an op runs its Pallas kernel.  ``tpu_pallas`` is the op's
+    named per-kernel choice for the TPU: False sends its TPU calls to
+    the op's XLA implementation (a kernel body Mosaic cannot lower),
+    while a forced "pallas" mode still runs the kernel, interpreted,
+    off the TPU."""
     if _FORCE["mode"] == "pallas":
         return True
     if _FORCE["mode"] == "ref":
         return False
-    return jax.default_backend() == "tpu"
+    return tpu_pallas and on_tpu()
+
+
+def interpret() -> bool:
+    """Run Pallas kernels under the interpreter: only off the TPU.  On a
+    TPU every Pallas call compiles; nothing falls back."""
+    return not on_tpu()

@@ -1,9 +1,10 @@
 """Batched min-cost assignment (Jonker-Volgenant) as a Pallas kernel.
 
 One grid cell per cost matrix: the whole (N, N) matrix lives in VMEM and
-the augmenting-path search runs as ``lax.while_loop``s over (N+1,)-vectors
-— association matrices are tiny (N <= max_tracks = 64), so a matrix is a
-single block and the batch axis is embarrassingly parallel.
+the augmenting-path search runs as ``lax.while_loop``s over (1, N+1)
+rows and an (N+1, 1) column — association matrices are tiny (N <=
+max_tracks = 64), so a matrix is a single block and the batch axis is
+embarrassingly parallel.
 
 The solver mirrors ``repro.core.hungarian._hungarian_np`` (potentials +
 augmenting paths, first-index argmin tie-break) but runs in float32 and
@@ -21,9 +22,94 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+
+def _at(vec, idx, j):
+    """``vec[j]`` for a traced scalar ``j`` as one comparison and one
+    reduction (exact: a single term survives the mask).  Mosaic lowers
+    neither a dynamic gather nor a scatter on a value; this form runs
+    unchanged in the Pallas body and under XLA."""
+    if jnp.issubdtype(vec.dtype, jnp.floating):
+        return jnp.max(jnp.where(idx == j, vec, -jnp.inf))
+    return jnp.sum(jnp.where(idx == j, vec, 0))
+
+
+def solve_padded(a, eff_n=None):
+    """The JV core on the 1-indexed matrix: a (M, M) finite f32 with a
+    dummy row and column 0 (M = N + 1) -> (M, 1) int32 matched column
+    per row, rows 0..N-1 valid.
+
+    Row-indexed state (the potential ``u``) is an (M, 1) column and
+    column-indexed state (``v``, ``p``, ``way``, ``minv``, ``used``) a
+    (1, M) row, so every update is an elementwise select and every
+    index read is ``_at`` — no gather, no scatter, no transpose."""
+    M = a.shape[0]
+    N = M - 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, M), 1)    # column j
+    rowv = jax.lax.broadcasted_iota(jnp.int32, (M, 1), 0)    # row i
+    eff = jnp.int32(N) if eff_n is None else \
+        jnp.asarray(eff_n, jnp.int32)
+    col_ok = lane <= eff
+    inf = jnp.float32(jnp.inf)
+
+    def outer(i, carry):
+        u, v, p = carry
+        # skipped rows (i > eff_n) park p[0] at 0: both while loops'
+        # conditions are then false on entry, so the row is a no-op —
+        # crucially WITHOUT lax.cond, which vmap turns into a select
+        # that executes the loop body even for skipped rows (and an
+        # all-masked argmin then never terminates)
+        p = jnp.where(lane == 0, jnp.where(i <= eff, i, 0), p)
+
+        def scan_cond(c):
+            return _at(p, lane, c[0]) != 0
+
+        def scan_body(c):
+            j0, u, v, way, minv, used = c
+            used = jnp.where(lane == j0, 1, used)
+            i0 = _at(p, lane, j0)
+            a_i0 = jnp.max(jnp.where(rowv == i0, a, -inf), axis=0,
+                           keepdims=True)                # row i0, (1, M)
+            cur = a_i0 - _at(u, rowv, i0) - v
+            free = used == 0
+            take = free & (cur < minv)
+            minv = jnp.where(take, cur, minv)
+            way = jnp.where(take, j0, way)
+            masked = jnp.where(free & col_ok, minv, inf)
+            delta = jnp.min(masked)
+            j1 = jnp.min(jnp.where(masked == delta, lane, M))  # first on ties
+            # u[p[j]] += delta over used columns j (matched rows are
+            # distinct, so the (M, M) membership mask is a safe scatter)
+            hit = (p == rowv) & (used > 0)
+            row_hit = jnp.max(hit.astype(jnp.int32), axis=1,
+                              keepdims=True) > 0
+            u = jnp.where(row_hit, u + delta, u)
+            v = jnp.where(used > 0, v - delta, v)
+            minv = jnp.where(free, minv - delta, minv)
+            return j1, u, v, way, minv, used
+
+        j0, u, v, way, _, _ = jax.lax.while_loop(
+            scan_cond, scan_body,
+            (jnp.int32(0), u, v, jnp.zeros((1, M), jnp.int32),
+             jnp.full((1, M), inf, jnp.float32),
+             jnp.zeros((1, M), jnp.int32)))
+
+        def aug_body(c):
+            j0, p = c
+            j1 = _at(way, lane, j0)
+            return j1, jnp.where(lane == j0, _at(p, lane, j1), p)
+
+        _, p = jax.lax.while_loop(lambda c: c[0] != 0, aug_body, (j0, p))
+        return u, v, p
+
+    _, _, p = jax.lax.fori_loop(
+        1, M, outer, (jnp.zeros((M, 1), jnp.float32),
+                      jnp.zeros((1, M), jnp.float32),
+                      jnp.zeros((1, M), jnp.int32)))
+    # invert p[j] = row matched to col j (1-indexed) into col per row;
+    # rows no column holds (skipped, past eff_n) report col 0 like the
+    # numpy twin
+    hit = (p == rowv + 1) & (lane >= 1)
+    return jnp.sum(jnp.where(hit, lane - 1, 0), axis=1, keepdims=True)
 
 
 def solve_one(cost, eff_n=None):
@@ -43,90 +129,34 @@ def solve_one(cost, eff_n=None):
     potentials, and f32 rounding of real-cost differences then depends
     on which padding columns the search walked.  Rows at or past
     ``eff_n`` report column 0."""
-    N = cost.shape[0]
     a = jnp.pad(cost.astype(jnp.float32), ((1, 0), (1, 0)))  # row/col 0 dummy
-    rows1 = jnp.arange(N + 1, dtype=jnp.int32)
-    eff = jnp.int32(N) if eff_n is None else \
-        jnp.asarray(eff_n, jnp.int32)
-    col_ok = rows1 <= eff
-
-    def outer(i, carry):
-        u, v, p = carry
-        # skipped rows (i > eff_n) park p[0] at 0: both while loops'
-        # conditions are then false on entry, so the row is a no-op —
-        # crucially WITHOUT lax.cond, which vmap turns into a select
-        # that executes the loop body even for skipped rows (and an
-        # all-masked argmin then never terminates)
-        p = p.at[0].set(jnp.where(i <= eff, i, 0))
-
-        def scan_cond(c):
-            j0, _u, _v, _way, _minv, _used = c
-            return p[j0] != 0
-
-        def scan_body(c):
-            j0, u, v, way, minv, used = c
-            used = used.at[j0].set(True)
-            i0 = p[j0]
-            cur = a[i0] - u[i0] - v                      # (N+1,)
-            free = ~used
-            take = free & (cur < minv)
-            minv = jnp.where(take, cur, minv)
-            way = jnp.where(take, j0, way)
-            masked = jnp.where(free & col_ok, minv, jnp.inf)
-            j1 = jnp.argmin(masked).astype(jnp.int32)    # first index on ties
-            delta = masked[j1]
-            # u[p[j]] += delta over used columns j (matched rows are
-            # distinct, so the O(N^2) membership mask is a safe scatter)
-            row_hit = ((p[None, :] == rows1[:, None]) & used[None, :]).any(1)
-            u = jnp.where(row_hit, u + delta, u)
-            v = jnp.where(used, v - delta, v)
-            minv = jnp.where(free, minv - delta, minv)
-            return j1, u, v, way, minv, used
-
-        j0, u, v, way, _, _ = jax.lax.while_loop(
-            scan_cond, scan_body,
-            (jnp.int32(0), u, v, jnp.zeros(N + 1, jnp.int32),
-             jnp.full(N + 1, jnp.inf, jnp.float32),
-             jnp.zeros(N + 1, bool)))
-
-        def aug_body(c):
-            j0, p = c
-            j1 = way[j0]
-            return j1, p.at[j0].set(p[j1])
-
-        _, p = jax.lax.while_loop(lambda c: c[0] != 0, aug_body, (j0, p))
-        return u, v, p
-
-    u0 = jnp.zeros(N + 1, jnp.float32)
-    p0 = jnp.zeros(N + 1, jnp.int32)
-    _, _, p = jax.lax.fori_loop(1, N + 1, outer, (u0, u0, p0))
-    # invert: p[j] = row matched to col j (1-indexed) -> col per row.
-    # Columns past eff_n stay at p == 0; route them to the explicit
-    # out-of-bounds index N so mode="drop" discards them (p - 1 would
-    # be -1, which jnp WRAPS to the last row before the bounds check),
-    # leaving skipped rows at col 0 like the numpy twin
-    idx = jnp.where(p[1:] > 0, p[1:] - 1, jnp.int32(N))
-    return jnp.zeros(N, jnp.int32).at[idx].set(
-        jnp.arange(N, dtype=jnp.int32), mode="drop")
+    return solve_padded(a, eff_n)[:-1, 0]
 
 
 def _assign_kernel(cost_ref, out_ref):
-    out_ref[...] = solve_one(cost_ref[...][0])[None, :]
+    out_ref[...] = solve_padded(cost_ref[...][0])[None]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def assign_pallas(costs, *, interpret: bool = False):
-    """costs: (K, N, N) finite f32 -> (K, N) int32 column per row."""
+    """costs: (K, N, N) finite f32 -> (K, N) int32 column per row.
+
+    The dummy row/column is padded on here, outside the kernel, and
+    each matrix's block is its whole (N+1, N+1) plane with a
+    (N+1, 1) result column: both keep the array's own last two dims,
+    as the TPU's (8, 128) tiling requires."""
     K, N, M = costs.shape
     assert N == M, "assign kernel operates on square (padded) matrices"
-    return pl.pallas_call(
+    a = jnp.pad(costs.astype(jnp.float32), ((0, 0), (1, 0), (1, 0)))
+    out = pl.pallas_call(
         _assign_kernel,
         grid=(K,),
-        in_specs=[pl.BlockSpec((1, N, N), lambda k: (k, 0, 0))],
-        out_specs=pl.BlockSpec((1, N), lambda k: (k, 0)),
-        out_shape=jax.ShapeDtypeStruct((K, N), jnp.int32),
-        compiler_params=_CompilerParams(
+        in_specs=[pl.BlockSpec((1, N + 1, N + 1), lambda k: (k, 0, 0))],
+        out_specs=pl.BlockSpec((1, N + 1, 1), lambda k: (k, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((K, N + 1, 1), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL,)),
         interpret=interpret,
         name="assign",
-    )(costs.astype(jnp.float32))
+    )(a)
+    return out[:, :N, 0]

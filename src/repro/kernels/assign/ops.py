@@ -14,12 +14,8 @@ from __future__ import annotations
 
 import jax
 
-from repro.kernels import use_pallas
+from repro.kernels import interpret, use_pallas
 from repro.kernels.assign.kernel import assign_pallas, solve_one
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 _solve_vmapped = jax.jit(jax.vmap(solve_one))
@@ -31,5 +27,5 @@ def assign_batch(costs):
 
     Returns (K, N) int32: matched column per row."""
     if use_pallas():
-        return assign_pallas(costs, interpret=_interpret())
+        return assign_pallas(costs, interpret=interpret())
     return _solve_vmapped(costs)

@@ -28,9 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -133,7 +130,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, sm_scale=None,
             pltpu.VMEM((bq, 1), jnp.float32),    # running denom
             pltpu.VMEM((bq, D), jnp.float32),    # fp32 accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=interpret,

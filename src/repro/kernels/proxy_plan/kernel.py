@@ -25,10 +25,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.proxy_plan.ref import STATS_W
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _plan_kernel(f_ref, w_ref, b_ref, t_ref, sy_ref, sx_ref,
                  grid_ref, stats_ref):
@@ -60,7 +56,7 @@ def _plan_kernel(f_ref, w_ref, b_ref, t_ref, sy_ref, sx_ref,
     xmax = jnp.max(jnp.where(mapped, ci, -1))
     zero = count * 0
     stats_ref[...] = jnp.stack(
-        [count, ymin, ymax, xmin, xmax, zero, zero, zero])[None]
+        [count, ymin, ymax, xmin, xmax, zero, zero, zero])[None, None]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -69,10 +65,13 @@ def proxy_plan_pallas(feat, w, b, threshold, span_y, span_x, *,
     """feat: (B, hp, wp, C); w: (C,); b, threshold: scalars;
     span_y: (hc, hp) f32; span_x: (wc, wp) f32.
 
-    Returns (mapped (B, hc, wc) int8, stats (B, STATS_W) int32)."""
+    Returns (mapped (B, hc, wc) int8, stats (B, STATS_W) int32).
+
+    Stats are written as (B, 1, STATS_W) so each frame's block keeps the
+    array's own last two dims, as the TPU's (8, 128) tiling requires."""
     B, hp, wp, C = feat.shape
     hc, wc = span_y.shape[0], span_x.shape[0]
-    return pl.pallas_call(
+    mapped, stats = pl.pallas_call(
         _plan_kernel,
         grid=(B,),
         in_specs=[
@@ -85,13 +84,13 @@ def proxy_plan_pallas(feat, w, b, threshold, span_y, span_x, *,
         ],
         out_specs=[
             pl.BlockSpec((1, hc, wc), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, STATS_W), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, STATS_W), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, hc, wc), jnp.int8),
-            jax.ShapeDtypeStruct((B, STATS_W), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, STATS_W), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL,)),
         interpret=interpret,
         name="proxy_plan",
@@ -99,3 +98,4 @@ def proxy_plan_pallas(feat, w, b, threshold, span_y, span_x, *,
       jnp.asarray(b, jnp.float32).reshape(1),
       jnp.asarray(threshold, jnp.float32).reshape(1),
       span_y.astype(jnp.float32), span_x.astype(jnp.float32))
+    return mapped, stats.reshape(B, STATS_W)

@@ -20,13 +20,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import use_pallas
+from repro.kernels import interpret, use_pallas
 from repro.kernels.proxy_plan.kernel import proxy_plan_pallas
 from repro.kernels.proxy_plan.ref import proxy_plan_ref
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.lru_cache(maxsize=None)
@@ -55,5 +51,5 @@ def proxy_plan(feat, w, b, threshold, *, grid_hw):
     span_x = jnp.asarray(span_matrix(wc, wp))
     if use_pallas():
         return proxy_plan_pallas(feat, w, b, threshold, span_y, span_x,
-                                 interpret=_interpret())
+                                 interpret=interpret())
     return proxy_plan_ref(feat, w, b, threshold, span_y, span_x)

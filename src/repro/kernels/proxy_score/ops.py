@@ -5,7 +5,7 @@ import functools
 
 import jax
 
-from repro.kernels import use_pallas
+from repro.kernels import interpret, use_pallas
 from repro.kernels.proxy_score.kernel import proxy_score_pallas
 from repro.kernels.proxy_score.ref import proxy_score_ref
 
@@ -17,5 +17,6 @@ def proxy_score(feat, w, b, threshold):
     feat: (B, Hc, Wc, C); w: (C,); b, threshold: scalars.
     """
     if use_pallas():
-        return proxy_score_pallas(feat, w, b, threshold)
+        return proxy_score_pallas(feat, w, b, threshold,
+                                  interpret=interpret())
     return proxy_score_ref(feat, w, b, threshold)

@@ -14,8 +14,7 @@ def smoke() -> None:
         feat = jax.random.normal(ks[0], (B, Hc, Wc, C))
         w = jax.random.normal(ks[1], (C,))
         sr, pr = proxy_score_ref(feat, w, 0.3, 0.5)
-        sp, pp = proxy_score_pallas(feat, w, 0.3, 0.5, block_m=32,
-                                    interpret=True)
+        sp, pp = proxy_score_pallas(feat, w, 0.3, 0.5, interpret=True)
         np.testing.assert_allclose(np.asarray(sp), np.asarray(sr),
                                    atol=1e-6)
         # thresholded int8 grid must be exact (plan paths depend on it)

@@ -51,9 +51,6 @@ from repro.core.fastmath import jx_matmul as _dot
 from repro.core.hungarian import FORBIDDEN_DEVICE
 from repro.kernels.assign.kernel import solve_one
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
 _F32 = jnp.float32
 _EIGHTH = np.float32(0.125)
@@ -186,7 +183,7 @@ def track_step_pallas(h_r, tbox_r, alive_r, te_gap_r, te_match, x, dbox,
         out_shape=(jax.ShapeDtypeStruct((K, Q), jnp.int32),
                    jax.ShapeDtypeStruct((K, Q, H), _F32),
                    jax.ShapeDtypeStruct((K, Q, H), _F32)),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL,)),
         interpret=interpret,
         name="track_step",

@@ -5,9 +5,10 @@ in ONE dispatch: detection features, match logits, cost assembly, JV
 assignment and both GRU batches (see ``kernel.py`` for the slot layout
 and ``kernels/README.md`` for the contract).
 
-Dispatch: Pallas on TPU (interpret=True when forced elsewhere); the
-default CPU path is the same ``step_core`` vmapped as plain jnp, so
-both paths share one algorithm bit for bit.  ``ref.py`` is the numpy
+Dispatch: ``TPU_PALLAS`` is False, so a TPU runs ``step_core``
+vmapped under XLA, as the CPU does; the Pallas body runs only when
+forced (``set_kernel_mode("pallas")``, interpreted off the TPU).  Both
+paths share one algorithm bit for bit.  ``ref.py`` is the numpy
 oracle.
 """
 from __future__ import annotations
@@ -18,7 +19,7 @@ import jax
 import numpy as np
 
 from repro.core import fastmath as fm
-from repro.kernels import use_pallas
+from repro.kernels import interpret, use_pallas
 from repro.kernels.track_step.kernel import step_core, track_step_pallas
 
 # flat operand order for the tracker heads, as produced by
@@ -44,9 +45,12 @@ def pack_params(np_params: Dict[str, np.ndarray]
     return tuple(out)
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
+# The fused body does not fit the TPU's kernel compiler: ``jx_matmul``
+# materializes (k, Q*Q, m) rank-1 products (over 400 MB at Q=128, far
+# past VMEM) and indexes them, the log1p table and the solved columns
+# with dynamic gathers Mosaic does not lower.  The TPU therefore runs
+# the same ``step_core`` under XLA.
+TPU_PALLAS = False
 
 _step_vmapped = jax.jit(jax.vmap(step_core,
                                  in_axes=(0,) * 8 + (None,) * 14))
@@ -61,9 +65,9 @@ def track_step(h_r, tbox_r, alive_r, te_gap_r, te_match, x, dbox, dvalid,
 
     Returns (matched (K, Q) int32 det column per ranked row or -1,
     h_upd (K, Q, H), h_new (K, Q, H))."""
-    if use_pallas():
+    if use_pallas(TPU_PALLAS):
         return track_step_pallas(h_r, tbox_r, alive_r, te_gap_r, te_match,
                                  x, dbox, dvalid, thr, params, table,
-                                 interpret=_interpret())
+                                 interpret=interpret())
     return _step_vmapped(h_r, tbox_r, alive_r, te_gap_r, te_match, x,
                          dbox, dvalid, thr, *params, table[:, 0])

@@ -22,15 +22,11 @@ import functools
 import jax.numpy as jnp
 import jax
 
-from repro.kernels import use_pallas
+from repro.kernels import interpret, use_pallas
 from repro.kernels.window_gather.kernel import (CELL, window_gather_pallas,
                                                 window_gather_batch_pallas)
 from repro.kernels.window_gather.ref import (window_gather_ref,
                                              window_gather_batch_ref)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("win_h", "win_w", "cell"))
@@ -41,7 +37,7 @@ def window_gather(frame, cell_origins, *, win_h: int, win_w: int,
     if use_pallas():
         return window_gather_pallas(frame, cell_origins,
                                     win_h=win_h, win_w=win_w, cell=cell,
-                                    interpret=_interpret())
+                                    interpret=interpret())
     return window_gather_ref(frame, cell_origins * cell,
                              win_h=win_h, win_w=win_w)
 
@@ -58,6 +54,6 @@ def window_gather_batch(frames, window_table, *, win_h: int, win_w: int,
         return window_gather_batch_pallas(frames, window_table,
                                           win_h=win_h, win_w=win_w,
                                           cell=cell,
-                                          interpret=_interpret())
+                                          interpret=interpret())
     tbl = window_table * jnp.asarray([1, cell, cell], jnp.int32)
     return window_gather_batch_ref(frames, tbl, win_h=win_h, win_w=win_w)

@@ -106,6 +106,8 @@ print("MULTIDEVICE-OK")
 def test_chunk_round_robin_across_four_devices():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    # forced host devices are CPU devices: never reach for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(_REPO, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     proc = subprocess.run([sys.executable, "-c", _SCRIPT], cwd=_REPO,
