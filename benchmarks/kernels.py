@@ -133,12 +133,6 @@ def run() -> List[Dict]:
                  "us_per_call": us,
                  "tpu_est_us": (flops / PEAK
                                 + K * Q * Q * Q * 4 / BW) * 1e6})
-
-    from repro.obs.metrics import REGISTRY
-    for r in rows:
-        slug = r["name"].split()[0]
-        REGISTRY.histogram(
-            f"kernels.{slug}.us_per_call").observe(r["us_per_call"])
     return rows
 
 

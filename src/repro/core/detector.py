@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.common import ParamBuilder, build
+from repro.obs.trace import NO_SPAN, TRACER
 
 STRIDE = 16
 
@@ -240,26 +241,39 @@ class Detector:
 
         origins/scales: per-frame window placement (see
         decode_detections); default full frame.  n_valid: decode only the
-        first n_valid rows (the rest are bucket padding)."""
+        first n_valid rows (the rest are bucket padding).  Host frames
+        are uploaded here; device frames (window crops) are not."""
         self.dispatches += 1
         self._m_dispatches.inc()
-        scores, boxes = _detect_scores(self.params,
-                                       jnp.asarray(frames), self.arch)
-        scores = np.asarray(scores)
         n = frames.shape[0] if n_valid is None else n_valid
-        hit = (scores[:n] > conf).any(axis=(1, 2))
-        boxes = np.asarray(boxes) if hit.any() else None
-        empty = np.zeros((0, 5), np.float32)
-        out = []
-        for b in range(n):
-            if not hit[b]:
-                out.append(empty)
-                continue
-            o = origins[b] if origins is not None else (0.0, 0.0)
-            s = scales[b] if scales is not None else (1.0, 1.0)
-            out.append(decode_detections(scores[b], boxes[b], conf,
-                                         origin=o, scale=s,
-                                         max_dets=max_dets))
+        x = frames
+        if isinstance(frames, np.ndarray):
+            with TRACER.span("detect.upload", "detect",
+                             args={"h2d_bytes": frames.nbytes}) \
+                    if TRACER.enabled else NO_SPAN:
+                x = jnp.asarray(frames)
+        with TRACER.span("detect.wait", "detect") \
+                if TRACER.enabled else NO_SPAN:
+            scores, boxes = _detect_scores(self.params, x, self.arch)
+            del x       # an uploaded batch is freed with its computation
+            scores = np.asarray(scores)
+            hit = (scores[:n] > conf).any(axis=(1, 2))
+            boxes = np.asarray(boxes) if hit.any() else None
+        with TRACER.span("detect.decode", "detect", args={"windows": n}) \
+                if TRACER.enabled else NO_SPAN as sp:
+            empty = np.zeros((0, 5), np.float32)
+            out = []
+            for b in range(n):
+                if not hit[b]:
+                    out.append(empty)
+                    continue
+                o = origins[b] if origins is not None else (0.0, 0.0)
+                s = scales[b] if scales is not None else (1.0, 1.0)
+                out.append(decode_detections(scores[b], boxes[b], conf,
+                                             origin=o, scale=s,
+                                             max_dets=max_dets))
+            if sp is not None:
+                sp.args["dets"] = sum(len(d) for d in out)
         return out
 
     def detect_batch_bucketed(self, frames: np.ndarray, conf: float,

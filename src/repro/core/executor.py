@@ -72,7 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.detector import next_bucket, nms
+from repro.core.detector import next_bucket, nms, pad_to_bucket
 from repro.core.pipeline import (CELL_PX, ModelBank, PipelineParams,
                                  RunResult, det_grid, downsample_chunk,
                                  make_sizeset, map_proxy_grid,
@@ -83,7 +83,7 @@ from repro.core.windows import (ChunkPlan, full_frame_plan, plan_chunk,
 from repro.data.video_synth import Clip
 from repro.obs.metrics import REGISTRY, RunProfile, drift_enabled
 from repro.obs.recorder import crash_dump
-from repro.obs.trace import TRACER
+from repro.obs.trace import NO_SPAN, TRACER
 
 DEFAULT_CHUNK = 16     # frames per chunk (B) when θ does not say
 
@@ -445,22 +445,22 @@ class BatchBroker:
             groups.setdefault(key, []).append(req)
         stats: List[Tuple[int, int]] = []
         for reqs in groups.values():
-            d0 = time.perf_counter_ns() if fsp is not None else 0
-            try:
-                stats.append(self._dispatch(reqs))
-            except BaseException as exc:
-                for r in reqs:
-                    r.error = exc
-                    r.done = True
-            else:
-                if fsp is not None:
-                    total, bucket = stats[-1]
-                    TRACER.emit(
-                        "broker.detect.dispatch", "broker", ts=d0,
-                        dur=time.perf_counter_ns() - d0, parent=fsp.sid,
-                        args={"windows": total, "bucket": bucket,
-                              "streams": len(reqs),
-                              "fill": round(total / bucket, 3)})
+            # a context span, so the detector's own spans nest under it
+            with TRACER.span("broker.detect.dispatch", "broker",
+                             parent=fsp.sid) if fsp is not None \
+                    else NO_SPAN as dsp:
+                try:
+                    stats.append(self._dispatch(reqs))
+                except BaseException as exc:
+                    for r in reqs:
+                        r.error = exc
+                        r.done = True
+                else:
+                    if dsp is not None:
+                        total, bucket = stats[-1]
+                        dsp.args = {"windows": total, "bucket": bucket,
+                                    "streams": len(reqs),
+                                    "fill": round(total / bucket, 3)}
         if fsp is not None:
             TRACER.close(fsp)
         return stats
@@ -899,9 +899,12 @@ class _RunContext:
 
     def upload(self, task: ChunkTask):
         """Pad the chunk to B frames (one gather jit shape) and place it
-        on this chunk's device / mesh sharding."""
+        on this chunk's device / mesh sharding.  With tracing on, the
+        calling thread's innermost span records the bytes sent."""
         padded = np.zeros((self.chunk, self.H, self.W, 3), np.float32)
         padded[:task.frames.shape[0]] = task.frames
+        if TRACER.enabled:
+            TRACER.add("h2d_bytes", padded.nbytes)
         if self.sharding is not None:
             return jax.device_put(padded, self.sharding)
         if len(self.devices) > 1:
@@ -946,16 +949,26 @@ def stage_proxy(ctx: _RunContext, task: ChunkTask) -> ChunkTask:
     with ctx.placed(task):
         if ctx.proxy is not None:
             ctx.profile.dispatch("proxy")
-            pframes = downsample_chunk(task.frames, ctx.proxy.resolution)
+            with TRACER.span("proxy.downsample", "proxy") \
+                    if TRACER.enabled else NO_SPAN:
+                pframes = downsample_chunk(task.frames,
+                                           ctx.proxy.resolution)
+            # the proxy pads the chunk to its bucket before the upload
+            with TRACER.span("proxy.wait", "proxy", args={
+                    "h2d_bytes": next_bucket(len(pframes))
+                    * pframes[0].nbytes}) \
+                    if TRACER.enabled else NO_SPAN:
+                if ctx.fused_plan:
+                    grids, stats = ctx.proxy.plan_batch(
+                        pframes, ctx.params.proxy_threshold, ctx.grid)
+                else:
+                    _, pos = ctx.proxy.scores_batch(
+                        pframes, ctx.params.proxy_threshold)
             if ctx.fused_plan:
-                grids, stats = ctx.proxy.plan_batch(
-                    pframes, ctx.params.proxy_threshold, ctx.grid)
                 task.plan = plan_from_mapped(grids, stats, ctx.sizeset,
                                              ctx.cfg.windows.max_windows,
                                              chunk_size=ctx.chunk)
             else:
-                _, pos = ctx.proxy.scores_batch(pframes,
-                                                ctx.params.proxy_threshold)
                 grids = [map_proxy_grid(p, ctx.grid) for p in pos]
                 task.plan = plan_chunk(grids, ctx.sizeset,
                                        ctx.cfg.windows.max_windows,
@@ -991,21 +1004,34 @@ def stage_detect(ctx: _RunContext, task: ChunkTask) -> ChunkTask:
             ctx.profile.dispatch("detect")
             if (pw, ph) == (W, H):
                 # full-frame windows: the crop is the frame itself
-                stack = frames[[slot for (slot, _, _, _) in entries]]
                 if broker is not None:
+                    # the broker pads and uploads the consolidated batch
+                    with TRACER.span("detect.upload", "detect") \
+                            if TRACER.enabled else NO_SPAN:
+                        stack = frames[[slot for (slot, _, _, _)
+                                        in entries]]
                     dets = broker.detect(detector, stack,
                                          ctx.params.det_conf,
                                          origins, scales, n)
                 else:
-                    dets = detector.detect_batch_bucketed(
+                    # detect_batch uploads the padded batch
+                    with TRACER.span("detect.upload", "detect") \
+                            if TRACER.enabled else NO_SPAN:
+                        stack = pad_to_bucket(
+                            frames[[slot for (slot, _, _, _) in entries]])
+                    dets = detector.detect_batch(
                         stack, ctx.params.det_conf, origins=origins,
-                        scales=scales)
+                        scales=scales, n_valid=n)
             else:
-                if frames_dev is None:       # lazy path (no double buffer)
-                    frames_dev = ctx.upload(task)
-                tbl = np.zeros((next_bucket(n), 3), np.int32)
-                for k, (slot, x, y, _) in enumerate(entries):
-                    tbl[k] = (slot, y, x)
+                with TRACER.span("detect.upload", "detect") \
+                        if TRACER.enabled else NO_SPAN:
+                    if frames_dev is None:   # lazy path (no double buffer)
+                        frames_dev = ctx.upload(task)
+                    tbl = np.zeros((next_bucket(n), 3), np.int32)
+                    for k, (slot, x, y, _) in enumerate(entries):
+                        tbl[k] = (slot, y, x)
+                    if TRACER.enabled:
+                        TRACER.add("h2d_bytes", tbl.nbytes)
                 from repro.kernels.window_gather import window_gather_batch
                 crops = window_gather_batch(frames_dev, tbl,
                                             win_h=ph, win_w=pw, cell=CELL_PX)
@@ -1022,20 +1048,26 @@ def stage_detect(ctx: _RunContext, task: ChunkTask) -> ChunkTask:
             for (slot, _, _, wi), d in zip(entries, dets):
                 per_window[(slot, wi)] = d
 
-        merged: List[np.ndarray] = []
-        for slot, wins in enumerate(plan.windows):
-            if not wins:
-                merged.append(np.zeros((0, 5), np.float32))
-            elif len(wins) == 1 and wins[0][2] == ctx.sizeset.full:
-                # the per-frame fast path applies no cross-window NMS
-                merged.append(per_window[(slot, 0)])
-            else:
-                by_size_frame: Dict[Tuple[int, int], List[int]] = {}
-                for wi, (_, _, s) in enumerate(wins):
-                    by_size_frame.setdefault(s, []).append(wi)
-                parts = [per_window[(slot, wi)]
-                         for wis in by_size_frame.values() for wi in wis]
-                merged.append(nms(np.concatenate(parts)))
+        with TRACER.span("detect.decode", "detect",
+                         args={"windows": len(per_window)}) \
+                if TRACER.enabled else NO_SPAN as sp:
+            merged: List[np.ndarray] = []
+            for slot, wins in enumerate(plan.windows):
+                if not wins:
+                    merged.append(np.zeros((0, 5), np.float32))
+                elif len(wins) == 1 and wins[0][2] == ctx.sizeset.full:
+                    # the per-frame fast path applies no cross-window NMS
+                    merged.append(per_window[(slot, 0)])
+                else:
+                    by_size_frame: Dict[Tuple[int, int], List[int]] = {}
+                    for wi, (_, _, s) in enumerate(wins):
+                        by_size_frame.setdefault(s, []).append(wi)
+                    parts = [per_window[(slot, wi)]
+                             for wis in by_size_frame.values()
+                             for wi in wis]
+                    merged.append(nms(np.concatenate(parts)))
+            if sp is not None:
+                sp.args["dets"] = sum(len(d) for d in merged)
         task.dets = merged
         # steer the decode worker's eager upload (a stale read just means
         # one lazy upload): this chunk gathered iff any size class was
@@ -1069,11 +1101,15 @@ def stage_track(ctx: _RunContext, task: ChunkTask) -> ChunkTask:
                                   ctx.cfg.tracker, task.frames,
                                   task.dets,
                                   min_bucket=max(8, ctx.chunk // 2))
-        ctx.tracker.step_chunk(task.frame_ids, task.dets, task.frames,
-                               embeds=embeds)
-    else:
-        for k, f in enumerate(task.frame_ids):
-            ctx.tracker.step(f, task.dets[k], task.frames[k])
+    with TRACER.span("track.assoc", "track",
+                     args={"frames": len(task.frame_ids)}) \
+            if TRACER.enabled else NO_SPAN:
+        if ctx.batch_embed:
+            ctx.tracker.step_chunk(task.frame_ids, task.dets, task.frames,
+                                   embeds=embeds)
+        else:
+            for k, f in enumerate(task.frame_ids):
+                ctx.tracker.step(f, task.dets[k], task.frames[k])
     task.frames = None
     return task
 
@@ -1089,27 +1125,30 @@ def _timed(name: str, fn: Callable) -> Callable:
     calling thread, so overlapped stages (decode on workers, compute on
     the draining thread) sum to honest per-stage CPU rather than
     double-counting each other.  With tracing on, the same interval is
-    also emitted as a ``stage.{name}`` span parented to the run's root
-    (explicitly — decode runs on worker threads whose thread-local span
-    stack is empty)."""
+    wrapped in a ``stage.{name}`` span, opened before the stage runs so
+    that spans opened inside it are its children.  Its parent is the
+    run's root, given explicitly: decode runs on worker threads whose
+    thread-local span stack is empty."""
     span_name = f"stage.{name}"
 
-    def wrapper(ctx: _RunContext, task: ChunkTask) -> ChunkTask:
+    def timed(ctx: _RunContext, task: ChunkTask) -> ChunkTask:
         t0 = time.perf_counter_ns()
         c0 = time.thread_time_ns()
         try:
             return fn(ctx, task)
         finally:
-            dur = time.perf_counter_ns() - t0
-            proc = time.thread_time_ns() - c0
-            ctx.profile.note_stage(name, dur / 1e9, proc / 1e9)
-            if TRACER.enabled:
-                root = ctx.run_span
-                TRACER.emit(span_name, "stage", ts=t0, dur=dur,
-                            proc=proc, stream=ctx.stream,
-                            chunk=task.index,
-                            parent=root.sid if root is not None
-                            else None)
+            ctx.profile.note_stage(name,
+                                   (time.perf_counter_ns() - t0) / 1e9,
+                                   (time.thread_time_ns() - c0) / 1e9)
+
+    def wrapper(ctx: _RunContext, task: ChunkTask) -> ChunkTask:
+        if not TRACER.enabled:
+            return timed(ctx, task)
+        root = ctx.run_span
+        with TRACER.span(span_name, "stage", stream=ctx.stream,
+                         chunk=task.index,
+                         parent=root.sid if root is not None else None):
+            return timed(ctx, task)
     return wrapper
 
 

@@ -33,6 +33,7 @@ from repro.configs.multiscope import TrackerConfig
 from repro.core import fastmath as fm
 from repro.core.hungarian import BIG, hungarian_device_np
 from repro.models.common import ParamBuilder, build
+from repro.obs.trace import NO_SPAN, TRACER
 from repro.optim import adamw
 
 BOX_FEATS = 6      # cx, cy, w, h, t_elapsed/8, log1p(t_elapsed)
@@ -938,14 +939,19 @@ def embed_dets_chunk(params, cfg: TrackerConfig,
         return [np.zeros((0, cfg.embed_dim), np.float32)
                 for _ in counts]
     from repro.core.detector import next_bucket
-    npad = next_bucket(total, min_bucket=min_bucket)
-    crops = np.zeros((npad, C, C, 3), np.float32)
-    k = 0
-    for frame, dets in zip(frames, dets_per_frame):
-        if len(dets):
-            crops[k:k + len(dets)] = extract_crops(frame, dets, C)
-            k += len(dets)
-    x = np.asarray(crop_embed(params, jnp.asarray(crops)))
+    with TRACER.span("track.crops", "track", args={"crops": total}) \
+            if TRACER.enabled else NO_SPAN:
+        npad = next_bucket(total, min_bucket=min_bucket)
+        crops = np.zeros((npad, C, C, 3), np.float32)
+        k = 0
+        for frame, dets in zip(frames, dets_per_frame):
+            if len(dets):
+                crops[k:k + len(dets)] = extract_crops(frame, dets, C)
+                k += len(dets)
+    with TRACER.span("track.wait", "track",
+                     args={"h2d_bytes": crops.nbytes}) \
+            if TRACER.enabled else NO_SPAN:
+        x = np.asarray(crop_embed(params, jnp.asarray(crops)))
     out = []
     k = 0
     for n in counts:

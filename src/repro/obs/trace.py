@@ -23,11 +23,24 @@ The instrumentation contract (tested by tests/test_obs.py):
     65536); older spans fall off the back.  An always-on stream can
     leave tracing enabled without growing memory per frame.
 
+While tracing is on, every context-manager span (``Tracer.span``, which
+the executor's stage wrapper uses too) is also entered as a
+``jax.profiler.TraceAnnotation`` of the same name, so the program's
+spans sit on the host plane of any JAX profile, on the device trace's
+clock.  With no profiler session running an annotation records nothing.
+Sites that need no span object guard with ``TRACER.enabled`` and fall
+back to ``NO_SPAN``, a shared do-nothing context manager::
+
+    with TRACER.span("detect.wait", "detect") if TRACER.enabled \
+            else NO_SPAN:
+        ...
+
 Span naming scheme (see src/repro/obs/README.md for the full table):
 
   ``run``                    one executor run (a clip, or one appended
                              segment of an open clip)
   ``stage.{decode,proxy,detect,track}``   one chunk through one stage
+  ``{proxy,detect,track}.*`` host work and device waits inside a stage
   ``broker.detect.flush``    one BatchBroker flush (its consolidated
                              dispatches are child spans)
   ``broker.detect.dispatch`` one consolidated detector call
@@ -42,11 +55,16 @@ import json
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Dict, List, Optional
 
-__all__ = ["Span", "Tracer", "TRACER", "enable", "disable", "enabled",
-           "export_jsonl", "export_chrome"]
+__all__ = ["Span", "Tracer", "TRACER", "NO_SPAN", "enable", "disable",
+           "enabled", "export_jsonl", "export_chrome"]
+
+# the disabled path's context manager: one shared instance, so a guarded
+# site (``TRACER.span(...) if TRACER.enabled else NO_SPAN``) allocates
+# nothing while tracing is off
+NO_SPAN = nullcontext()
 
 
 class Span:
@@ -99,6 +117,7 @@ class Tracer:
         self._spans: deque = deque(maxlen=self._capacity)  # guarded-by: _lock
         self._ids = itertools.count(1)
         self._tls = threading.local()
+        self._annotation = None      # jax.profiler.TraceAnnotation, lazily
 
     # -- lifecycle ------------------------------------------------------------
 
@@ -120,7 +139,7 @@ class Tracer:
 
     # -- recording ------------------------------------------------------------
 
-    def _stack(self) -> list:
+    def _stack(self) -> List[Span]:
         st = getattr(self._tls, "stack", None)
         if st is None:
             st = self._tls.stack = []
@@ -129,7 +148,19 @@ class Tracer:
     def current(self) -> Optional[int]:
         """The calling thread's innermost open context-span id."""
         st = getattr(self._tls, "stack", None)
-        return st[-1] if st else None
+        return st[-1].sid if st else None
+
+    def add(self, key: str, value: int) -> None:
+        """Add ``value`` to the ``key`` arg of the calling thread's
+        innermost open context span (what a span sent to the device,
+        counted where it is sent).  Callers guard with ``if
+        TRACER.enabled:``."""
+        st = getattr(self._tls, "stack", None)
+        if st:
+            sp = st[-1]
+            if sp.args is None:
+                sp.args = {}
+            sp.args[key] = sp.args.get(key, 0) + value
 
     def emit(self, name: str, cat: str = "", *, ts: int, dur: int,
              proc: int = 0, stream: Optional[str] = None,
@@ -174,23 +205,49 @@ class Tracer:
     @contextmanager
     def span(self, name: str, cat: str = "", *,
              stream: Optional[str] = None, chunk: Optional[int] = None,
+             parent: Optional[int] = None,
              args: Optional[dict] = None):
         """Context-manager span; nested spans on the same thread parent
-        to it automatically.  Callers still guard with ``if
-        TRACER.enabled:`` so the disabled path allocates nothing."""
+        to it automatically.  ``parent`` defaults to the thread's
+        innermost open span, whose stream and chunk a span without its
+        own inherits; pass it explicitly where the thread's stack does
+        not hold the parent (a decode worker's stage span parents to
+        its run).  The span is also a ``jax.profiler.TraceAnnotation``
+        of the same name.  Callers still guard with ``TRACER.enabled``
+        so the disabled path allocates nothing."""
         if not self.enabled:
             yield None
             return
-        sp = self.open(name, cat, stream=stream, chunk=chunk, args=args)
         st = self._stack()
-        st.append(sp.sid)
+        if parent is None and st:
+            top = st[-1]
+            parent = top.sid
+            if stream is None:
+                stream = top.stream
+            if chunk is None:
+                chunk = top.chunk
+        sp = self.open(name, cat, stream=stream, chunk=chunk,
+                       parent=parent, args=args)
+        st.append(sp)
+        ann = self._annotate(name)
+        ann.__enter__()
         c0 = time.thread_time_ns()
         try:
             yield sp
         finally:
-            st.pop()
             sp.proc = time.thread_time_ns() - c0
+            ann.__exit__(None, None, None)
+            st.pop()
             self.close(sp)
+
+    def _annotate(self, name: str):
+        """A ``jax.profiler.TraceAnnotation`` (imported on first use:
+        the tracer itself does not need JAX)."""
+        cls = self._annotation
+        if cls is None:
+            from jax.profiler import TraceAnnotation as cls
+            self._annotation = cls
+        return cls(name)
 
     # -- reading / export -----------------------------------------------------
 

@@ -159,6 +159,175 @@ def test_tracing_collects_run_and_stage_spans(exec_bank):
         assert s.dur >= 0 and s.proc >= 0
 
 
+CHILD_STAGE = {"proxy.downsample": "stage.proxy",
+               "proxy.wait": "stage.proxy",
+               "detect.upload": "stage.detect",
+               "detect.wait": "stage.detect",
+               "detect.decode": "stage.detect",
+               "track.crops": "stage.track",
+               "track.wait": "stage.track",
+               "track.assoc": "stage.track"}
+
+
+def _traced_recorded_run(bank, params, clip, options):
+    """A traced run that also keeps each chunk's window plan and
+    detections -> (spans, {chunk: plan}, {chunk: dets})."""
+    from repro.core import executor as ex
+    plans, dets = {}, {}
+
+    def detect(ctx, task):
+        out = ex.stage_detect(ctx, task)
+        plans[task.index], dets[task.index] = out.plan, out.dets
+        return out
+    TRACER.enable()
+    TRACER.clear()
+    ex.ClipExecutor(bank, params, options,
+                    stages={"detect": detect}).run(clip)
+    spans = TRACER.snapshot()
+    TRACER.disable()
+    return spans, plans, dets
+
+
+@pytest.mark.parametrize("double_buffer", [False, True])
+def test_child_spans_nest_in_their_stage_and_count_uploads(
+        exec_bank, double_buffer):
+    """A traced run emits all eight child spans; each is a child of
+    its stage's span of the same chunk and lies inside it, and every
+    ``h2d_bytes`` is the ``nbytes`` of the padded host arrays the
+    shapes give: proxy frames, full-frame detector batches, the chunk
+    upload (in DECODE when double-buffered, else lazily in DETECT) with
+    each window table, and the crop batch."""
+    from repro.core.detector import next_bucket
+    from repro.core.pipeline import CELL_PX
+    bank, clips, res, th = exec_bank
+    B = 8
+    params = _params(bank, res, th, chunk_size=B, tracker="recurrent")
+    spans, plans, dets = _traced_recorded_run(
+        bank, params, clips[0],
+        ExecutorOptions(prefetch=False, double_buffer=double_buffer))
+    by_sid = {s.sid: s for s in spans}
+    assert {s.name for s in spans} >= set(CHILD_STAGE)
+    for s in spans:
+        if s.name not in CHILD_STAGE:
+            continue
+        p = by_sid[s.parent]
+        assert p.name == CHILD_STAGE[s.name], s.name
+        assert s.chunk == p.chunk and s.stream == p.stream
+        assert p.ts <= s.ts and s.ts + s.dur <= p.ts + p.dur, s.name
+
+    W, H = params.det_res
+    frame = H * W * 3 * 4
+    pw, ph = res
+    crop = bank.cfg.tracker.crop
+
+    def h2d(name, chunk):
+        return sum(s.args.get("h2d_bytes", 0) for s in spans
+                   if s.name == name and s.chunk == chunk and s.args)
+    for k, plan in plans.items():
+        n = len(plan.windows)
+        assert h2d("proxy.wait", k) == next_bucket(n) * ph * pw * 3 * 4
+        decoded = h2d("stage.decode", k)
+        assert decoded in (0, B * frame)
+        assert decoded == 0 or double_buffer
+        want, lazy = 0, decoded == 0
+        for size, entries in plan.by_size.items():
+            if (size[0] * CELL_PX, size[1] * CELL_PX) == (W, H):
+                want += next_bucket(len(entries)) * frame
+            else:
+                want += next_bucket(len(entries)) * 3 * 4
+                if lazy:
+                    want, lazy = want + B * frame, False
+        assert h2d("detect.upload", k) == want, k
+        total = sum(len(d) for d in dets[k])
+        wait = next_bucket(total, min_bucket=max(8, B // 2)) * crop \
+            * crop * 3 * 4 if total else 0
+        assert h2d("track.wait", k) == wait, k
+
+
+def test_disabled_sites_never_reach_the_tracer(exec_bank, monkeypatch):
+    """Tracing off: no site opens, emits or annotates a span (so none
+    allocates a context manager, takes a tracer timestamp or a lock),
+    on the paths the child spans instrument."""
+    from repro.obs.trace import Tracer
+    bank, clips, res, th = exec_bank
+
+    def refuse(*a, **kw):
+        raise AssertionError("a disabled site reached the tracer")
+    for name in ("span", "open", "emit", "add", "_annotate"):
+        monkeypatch.setattr(Tracer, name, refuse)
+    TRACER.disable()
+    for opts in (ExecutorOptions(prefetch=False, double_buffer=False),
+                 ExecutorOptions()):
+        run_clip_streamed(bank, _params(bank, res, th, chunk_size=8,
+                                        tracker="recurrent"),
+                          clips[0], opts)
+
+
+def test_span_parent_inheritance_and_add():
+    """``parent=`` overrides the thread's stack; a span without its
+    own stream and chunk takes its parent's; ``add`` accumulates into
+    the innermost open span only."""
+    TRACER.enable()
+    TRACER.clear()
+    root = TRACER.open("run", "test", stream="cam1")
+    with TRACER.span("stage.x", "test", stream="cam1", chunk=3,
+                     parent=root.sid) as st:
+        TRACER.add("h2d_bytes", 5)
+        with TRACER.span("x.child", "test", args={"k": 1}) as ch:
+            TRACER.add("h2d_bytes", 2)
+        TRACER.add("h2d_bytes", 7)
+    TRACER.close(root)
+    TRACER.disable()
+    assert st.parent == root.sid and ch.parent == st.sid
+    assert (ch.stream, ch.chunk) == ("cam1", 3)
+    assert st.args == {"h2d_bytes": 12}
+    assert ch.args == {"k": 1, "h2d_bytes": 2}
+    assert TRACER.current() is None
+
+
+def test_spans_are_profiler_annotations_on_its_clock(exec_bank, tmp_path):
+    """With tracing on, a JAX profile holds host events named as the
+    spans (``stage.detect``, ``detect.decode``), one per span, and
+    their starts agree with the tracer's after the offset taken at an
+    anchor annotation, to within 1 ms."""
+    import glob
+    import time
+
+    import jax
+    bank, clips, res, th = exec_bank
+    params = _params(bank, res, th, chunk_size=8)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    TRACER.enable()
+    TRACER.clear()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("test.anchor"):
+            t0 = time.perf_counter_ns()
+            run_clip_streamed(bank, params, clips[0],
+                              ExecutorOptions(prefetch=False))
+    finally:
+        jax.profiler.stop_trace()
+        TRACER.disable()
+    spans = TRACER.snapshot()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    events = {}
+    for plane in pd.planes:
+        if plane.name.startswith("/host"):
+            for line in plane.lines:
+                for ev in line.events:
+                    events.setdefault(ev.name, []).append(ev.start_ns)
+    anchor, = events["test.anchor"]
+    offset = anchor - t0
+    for name in ("stage.detect", "detect.decode"):
+        got = sorted(events.get(name, []))
+        want = sorted(s.ts + offset for s in spans if s.name == name)
+        assert want and len(got) == len(want), name
+        assert max(abs(g - w) for g, w in zip(got, want)) < 1_000_000
+
+
 # ---------------------------------------------------------------------------
 # exporters
 # ---------------------------------------------------------------------------
