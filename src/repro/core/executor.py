@@ -1101,15 +1101,22 @@ def stage_track(ctx: _RunContext, task: ChunkTask) -> ChunkTask:
                                   ctx.cfg.tracker, task.frames,
                                   task.dets,
                                   min_bucket=max(8, ctx.chunk // 2))
+    tracker = ctx.tracker
     with TRACER.span("track.assoc", "track",
                      args={"frames": len(task.frame_ids)}) \
-            if TRACER.enabled else NO_SPAN:
+            if TRACER.enabled else NO_SPAN as sp:
+        # the recurrent tracker's host-twin work, as this chunk's deltas
+        work0 = (tracker.jv_steps, tracker.fma_ties) \
+            if sp is not None and hasattr(tracker, "jv_steps") else None
         if ctx.batch_embed:
-            ctx.tracker.step_chunk(task.frame_ids, task.dets, task.frames,
-                                   embeds=embeds)
+            tracker.step_chunk(task.frame_ids, task.dets, task.frames,
+                               embeds=embeds)
         else:
             for k, f in enumerate(task.frame_ids):
-                ctx.tracker.step(f, task.dets[k], task.frames[k])
+                tracker.step(f, task.dets[k], task.frames[k])
+        if work0 is not None:
+            sp.args["jv_steps"] = tracker.jv_steps - work0[0]
+            sp.args["fma_ties"] = tracker.fma_ties - work0[1]
     task.frames = None
     return task
 

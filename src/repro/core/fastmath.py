@@ -15,9 +15,10 @@ identical f32 bits:
 * ``fmadd`` — the only multiply-feeding-an-add pattern either flavor is
   allowed to write.  The jnp flavor is literally ``a * b + c`` (XLA
   contracts it to a single-rounding fma); the numpy flavor emulates that
-  fma exactly in f64 via Boldo-Melquiond round-to-odd (the 24+24-bit
-  product is exact in f64; a TwoSum residual decides the odd-rounding
-  nudge before the final f32 cast).
+  fma exactly in f64 (the 24+24-bit product is exact in f64): one f64
+  add and the f32 cast, with the few elements whose f64 sum lies on an
+  f32 rounding midpoint corrected by Boldo-Melquiond round-to-odd (a
+  TwoSum residual decides the odd-rounding nudge before the cast).
 * ``exp`` — Cody-Waite range reduction + the Cephes ``expf`` degree-5
   polynomial, every step either an ``fmadd`` or an exact op (floor,
   clip, power-of-two scale built by integer exponent bit-twiddling).
@@ -53,6 +54,8 @@ the single-multiply ``h + z*(c-h)``) — and any ``@`` / ``jnp.dot``.
 """
 from __future__ import annotations
 
+import threading
+
 import numpy as np
 
 _LOG2E = np.float32(1.44269504088896341)
@@ -81,11 +84,25 @@ LOG1P_TABLE = np.log1p(
 # numpy flavor (host)
 # ---------------------------------------------------------------------------
 
-def np_fmadd(a, b, c) -> np.ndarray:
-    """Exact f32 fma(a, b, c) — bit-identical to XLA CPU's contracted
-    ``a * b + c``.  f64 holds the 24x24-bit product exactly; TwoSum
-    recovers the residual of the f64 add, and round-to-odd on the f64
-    intermediate makes the final f32 cast single-rounded."""
+class _HostCounts(threading.local):
+    """Per-thread work counters of the host twins (read as deltas by
+    the caller that owns the thread's work, ``RecurrentTracker``)."""
+    fma_ties = 0        # elements ``np_fmadd`` sent to the exact path
+
+
+COUNTS = _HostCounts()
+
+# f64 bits of an f32 rounding midpoint in the f32-normal range: the
+# 24-bit significand plus a half (bit 28 set), nothing below it
+_MID_MASK = np.int64(0x1FFFFFFF)
+_MID_BITS = np.int64(0x10000000)
+_F32_TINY = np.float64(2.0 ** -126)
+
+
+def _np_fmadd_exact(a, b, c) -> np.ndarray:
+    """Exact f32 fma(a, b, c).  f64 holds the 24x24-bit product exactly;
+    TwoSum recovers the residual of the f64 add, and round-to-odd on the
+    f64 intermediate makes the final f32 cast single-rounded."""
     a64 = np.asarray(a, np.float64)
     b64 = np.asarray(b, np.float64)
     c64 = np.asarray(c, np.float64)
@@ -99,6 +116,32 @@ def np_fmadd(a, b, c) -> np.ndarray:
     dirn = np.where(err > 0, np.float64(np.inf), np.float64(-np.inf))
     s = np.where(fix, np.nextafter(s, dirn), s)
     return s.astype(np.float32)
+
+
+def np_fmadd(a, b, c) -> np.ndarray:
+    """Exact f32 fma(a, b, c) — bit-identical to XLA CPU's contracted
+    ``a * b + c`` and to ``_np_fmadd_exact`` on every input.
+
+    The f64 sum ``s`` of the exact product and ``c`` is rounded once
+    more to f32.  That second rounding can differ from a single
+    rounding of the exact sum only where ``s`` lies exactly on an f32
+    rounding midpoint, so only those elements go through the exact
+    path: the normal-range midpoint bit pattern, and every nonzero
+    ``s`` below the f32-normal range, where the pattern differs.
+    Zeros, infinities and NaNs need no correction (the exact path
+    leaves a zero or non-finite ``s`` as it is)."""
+    s = np.asarray(np.add(np.multiply(a, b, dtype=np.float64), c,
+                          dtype=np.float64))
+    out = s.astype(np.float32)
+    mag = np.abs(s)
+    slow = (s.view(np.int64) & _MID_MASK) == _MID_BITS
+    slow |= (mag < _F32_TINY) & (mag != 0)
+    n_slow = np.count_nonzero(slow)
+    if n_slow:
+        ba, bb, bc = np.broadcast_arrays(a, b, c)
+        out[slow] = _np_fmadd_exact(ba[slow], bb[slow], bc[slow])
+        COUNTS.fma_ties += int(n_slow)
+    return out
 
 
 def _np_pow2(k: np.ndarray) -> np.ndarray:

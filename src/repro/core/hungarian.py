@@ -15,6 +15,7 @@ through ``jax.pure_callback``.
 """
 from __future__ import annotations
 
+import threading
 from typing import List, Sequence, Tuple
 
 import numpy as np
@@ -113,49 +114,86 @@ def _hungarian_np(cost: np.ndarray) -> List[Tuple[int, int]]:
     return [(r, int(c)) for r, c in enumerate(col_of) if c >= 0]
 
 
+class _HostCounts(threading.local):
+    """Per-thread work counters of the host JV twin (read as deltas by
+    the caller that owns the thread's work, ``RecurrentTracker``)."""
+    jv_steps = 0        # column-scan steps of ``solve_device_np``
+
+
+COUNTS = _HostCounts()
+
+
 def solve_device_np(cost: np.ndarray) -> np.ndarray:
-    """Numpy float32 twin of ``kernels.assign.kernel.solve_one`` — a
-    line-by-line port (same update order, same first-index argmin
-    tie-break, same f32 arithmetic), so its output is bit-identical to
-    the device solver on the same matrix.  cost: (N, N) finite f32 ->
-    (N,) int32 matched column per row (full permutation)."""
+    """Numpy float32 twin of ``kernels.assign.kernel.solve_one``: the
+    same update order, the same first-index argmin tie-break and the
+    same f32 arithmetic, so its output is bit-identical to the device
+    solver on the same matrix.  cost: (N, N) finite f32 -> (N,) int32
+    matched column per row (full permutation).
+
+    Within one row's search the potentials of the rows and columns
+    already on the alternating tree are written every step but never
+    read: a row's ``u`` is read only in the step it joins, and a used
+    column's reduced cost is masked out.  So those potentials ride in
+    join-order buffers (``tu``, ``tv``), receive each step's ``delta``
+    in the same order and precision, and go back to ``u``/``v`` when
+    the search ends.  Meanwhile a used column parks ``v`` at -inf and
+    ``minv`` at +inf, so its reduced cost is +inf and neither takes
+    part in the compare nor wins the argmin: the masks of the kernel's
+    formulation become plain comparisons."""
     cost = np.asarray(cost, np.float32)
     N = cost.shape[0]
     a = np.zeros((N + 1, N + 1), np.float32)
     a[1:, 1:] = cost
-    rows1 = np.arange(N + 1, dtype=np.int32)
+    a_rows = list(a)
     u = np.zeros(N + 1, np.float32)
     v = np.zeros(N + 1, np.float32)
-    p = np.zeros(N + 1, np.int32)
+    p = [0] * (N + 1)                   # p[j] = row matched to col j
+    way = np.zeros(N + 1, np.int32)
+    minv = np.empty(N + 1, np.float32)
+    cur = np.empty(N + 1, np.float32)
+    take = np.empty(N + 1, bool)
+    tu = np.empty(N + 1, np.float32)    # u of the tree's rows, join order
+    tv = np.empty(N + 1, np.float32)    # v of the tree's columns
+    inf = np.float32(np.inf)
+    steps = 0
     for i in range(1, N + 1):
         p[0] = i
         j0 = 0
-        way = np.zeros(N + 1, np.int32)
-        minv = np.full(N + 1, np.inf, np.float32)
-        used = np.zeros(N + 1, bool)
-        while p[j0] != 0:
-            used[j0] = True
+        way.fill(0)
+        minv.fill(inf)
+        t_rows, t_cols = [], []
+        k = 0
+        while True:
             i0 = p[j0]
-            cur = (a[i0] - u[i0]) - v                    # f32 (N+1,)
-            free = ~used
-            take = free & (cur < minv)
-            minv = np.where(take, cur, minv)
-            way = np.where(take, j0, way).astype(np.int32)
-            masked = np.where(free, minv, np.float32(np.inf))
-            j1 = int(np.argmin(masked))                  # first index on ties
-            delta = masked[j1]
-            row_hit = ((p[None, :] == rows1[:, None])
-                       & used[None, :]).any(1)
-            u = np.where(row_hit, u + delta, u).astype(np.float32)
-            v = np.where(used, v - delta, v).astype(np.float32)
-            minv = np.where(free, minv - delta, minv).astype(np.float32)
-            j0 = j1
+            t_rows.append(i0)
+            t_cols.append(j0)
+            tu[k] = u[i0]
+            tv[k] = v[j0]
+            v[j0] = -inf
+            minv[j0] = inf
+            k += 1
+            np.subtract(a_rows[i0], u[i0], out=cur)
+            np.subtract(cur, v, out=cur)
+            np.less(cur, minv, out=take)
+            np.putmask(minv, take, cur)
+            np.putmask(way, take, j0)
+            j0 = int(minv.argmin())     # first index on ties
+            delta = minv[j0]
+            tu[:k] += delta
+            tv[:k] -= delta
+            minv -= delta
+            if p[j0] == 0:
+                break
+        steps += k
+        u[t_rows] = tu[:k]
+        v[t_cols] = tv[:k]
         while j0:
-            j1 = way[j0]
+            j1 = int(way[j0])
             p[j0] = p[j1]
             j0 = j1
+    COUNTS.jv_steps += steps
     col_of = np.zeros(N, np.int32)
-    col_of[p[1:] - 1] = np.arange(N, dtype=np.int32)
+    col_of[np.asarray(p[1:], np.intp) - 1] = np.arange(N, dtype=np.int32)
     return col_of
 
 

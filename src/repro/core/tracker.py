@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.configs.multiscope import TrackerConfig
 from repro.core import fastmath as fm
+from repro.core import hungarian as hg
 from repro.core.hungarian import BIG, hungarian_device_np
 from repro.models.common import ParamBuilder, build
 from repro.obs.trace import NO_SPAN, TRACER
@@ -447,6 +448,11 @@ class RecurrentTracker:
         # device dispatches issued by this tracker (crop CNN per-frame
         # fallback + track-step kernels); read by the TRACK stage timer
         self.dispatches = 0
+        # host-twin work done by ``step``: JV column-scan steps and
+        # elements ``np_fmadd`` routed through its exact tie path;
+        # read as deltas by the ``track.assoc`` span
+        self.jv_steps = 0
+        self.fma_ties = 0
 
     def _device_operands(self):
         if self._packed is None:
@@ -513,6 +519,7 @@ class RecurrentTracker:
         candidates and the GRU updates."""
         cfg = self.cfg
         n = len(dets)
+        jv0, ties0 = hg.COUNTS.jv_steps, fm.COUNTS.fma_ties
         te_scalar = 0.0 if self._last_frame is None else \
             float(frame_idx - self._last_frame)
         self._last_frame = frame_idx
@@ -621,6 +628,8 @@ class RecurrentTracker:
             self.active.sort(key=lambda t: -len(t.frames))
             self.finished.extend(self.active[self.cfg.max_tracks:])
             self.active = self.active[:self.cfg.max_tracks]
+        self.jv_steps += hg.COUNTS.jv_steps - jv0
+        self.fma_ties += fm.COUNTS.fma_ties - ties0
 
     def _device_step(self, frame_idx: int, te_scalar: float,
                      x: np.ndarray, boxes: np.ndarray):

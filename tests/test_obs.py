@@ -244,6 +244,38 @@ def test_child_spans_nest_in_their_stage_and_count_uploads(
         assert h2d("track.wait", k) == wait, k
 
 
+@pytest.mark.parametrize("threshold", ["calibrated", "skip_all"])
+def test_track_assoc_carries_host_twin_counters(exec_bank, threshold):
+    """Each traced chunk's ``track.assoc`` span carries the recurrent
+    tracker's ``jv_steps`` and ``fma_ties`` for that chunk: together
+    they are the run's whole host-twin work, and a chunk with no
+    detections reads 0 ``jv_steps``."""
+    from repro.core import fastmath as fm
+    from repro.core import hungarian as hg
+    bank, clips, res, th = exec_bank
+    th = th if threshold == "calibrated" else float("inf")
+    params = _params(bank, res, th, chunk_size=8, tracker="recurrent")
+    jv0, ties0 = hg.COUNTS.jv_steps, fm.COUNTS.fma_ties
+    spans, plans, dets = _traced_recorded_run(
+        bank, params, clips[0], ExecutorOptions(prefetch=False))
+    assoc = {s.chunk: s for s in spans if s.name == "track.assoc"}
+    assert set(assoc) == set(dets)
+    for k, s in assoc.items():
+        assert set(s.args) == {"frames", "jv_steps", "fma_ties"}
+        if not any(len(d) for d in dets[k]):
+            assert s.args["jv_steps"] == 0
+    assert sum(s.args["jv_steps"] for s in assoc.values()) \
+        == hg.COUNTS.jv_steps - jv0
+    assert sum(s.args["fma_ties"] for s in assoc.values()) \
+        == fm.COUNTS.fma_ties - ties0
+    n_dets = sum(len(d) for ds in dets.values() for d in ds)
+    if threshold == "skip_all":
+        assert n_dets == 0
+    else:
+        assert n_dets > 0
+        assert sum(s.args["jv_steps"] for s in assoc.values()) > 0
+
+
 def test_disabled_sites_never_reach_the_tracer(exec_bank, monkeypatch):
     """Tracing off: no site opens, emits or annotates a span (so none
     allocates a context manager, takes a tracer timestamp or a lock),
