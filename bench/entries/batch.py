@@ -66,7 +66,7 @@ def setup(cell, seed: int, seconds: float, log,
           fresh: bool = False) -> State:
     tr = cell.traffic
     t0 = time.perf_counter()
-    sys_ = models.build(cell.config, tr["profile"], log)
+    sys_ = models.build(cell.config, tr["profile"], log, cell.root)
     t1 = time.perf_counter()
     pool = fr.make_clips(tr["profile"], seed,
                          fr.pool_ids(tr, seed, fresh),
@@ -161,7 +161,7 @@ def check(st: State, control=None):
     W, H = st.sys.params.det_res
     return compare.check(streams(st), st.sys.weights, st.cell.config,
                          theta(st), lambda c, f: fr.frame(c, f, W, H),
-                         control=control)
+                         control=control, root=st.cell.root)
 
 
 def attempted(st: State):

@@ -7,26 +7,21 @@ channel, two operations each; the bias and activation are left out.  The least b
 must move are its input image, its weights and its final outputs, so
 intermediate activations kept on chip cost nothing: both counts err
 low, and a roofline share built on them errs low too.
+
+The detector's layer list lives with its family
+(``bench/lib/detectors/<family>.py``, whose ``work`` is built on
+``conv_flops`` and ``conv_bytes``); the proxy's is here.
 """
 from __future__ import annotations
 
 import math
 from typing import List, Sequence, Tuple
 
+from bench.lib.registry import ROOT, find_family
+
 F32 = 4
 
 Layer = Tuple[int, int, int, int]      # (k, stride, cin, cout)
-
-
-def detector_layers(channels: Sequence[int], extra: Sequence[int],
-                    head: int = 5) -> List[Layer]:
-    out, cin = [], 3
-    for c, e in zip(channels, extra):
-        out.append((3, 2, cin, c))
-        out.extend([(3, 1, c, c)] * e)
-        cin = c
-    out.append((1, 1, cin, head))
-    return out
 
 
 def proxy_layers(cell: int, base: int) -> List[Layer]:
@@ -68,14 +63,15 @@ def conv_bytes(layers: Sequence[Layer], h: int, w: int) -> float:
     return F32 * (h * w * 3 + weights + oh * ow * layers[-1][3])
 
 
-def detector_work(config: dict, theta: dict, sizes_cells, counters: dict
-                  ) -> Tuple[float, float]:
+def detector_work(config: dict, theta: dict, sizes_cells, counters: dict,
+                  root: str = ROOT) -> Tuple[float, float]:
     """(operations, bytes) of the detector over the window: every
     full-frame application at the detector's resolution, and every
     sub-frame window at the SMALLEST size of the set (the program counts
-    windows, not windows per size), so both are lower bounds."""
+    windows, not windows per size), so both are lower bounds.  One
+    application's counts are the family's ``work``."""
     d = config["detector"]
-    layers = detector_layers(d["channels"], d["extra_convs"])
+    work = find_family(d["family"], root).program.work
     W, H = theta["det_res"]
     cell = d["cell_px"]
     full = counters["full_frames"]
@@ -84,9 +80,9 @@ def detector_work(config: dict, theta: dict, sizes_cells, counters: dict
     small = min((s for s in sizes_cells if tuple(s) != grid),
                 key=lambda s: s[0] * s[1], default=grid)
     sw, sh = small[0] * cell, small[1] * cell
-    ops = full * conv_flops(layers, H, W) + sub * conv_flops(layers, sh, sw)
-    byt = full * conv_bytes(layers, H, W) + sub * conv_bytes(layers, sh, sw)
-    return ops, byt
+    full_ops, full_bytes = work(d, H, W)
+    sub_ops, sub_bytes = work(d, sh, sw)
+    return full * full_ops + sub * sub_ops, full * full_bytes + sub * sub_bytes
 
 
 def proxy_work(config: dict, theta: dict, counters: dict

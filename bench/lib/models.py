@@ -32,7 +32,7 @@ from typing import Dict, Tuple
 
 import numpy as np
 
-from bench.lib.registry import BENCH_DIR
+from bench.lib.registry import BENCH_DIR, ROOT, find_family
 
 CACHE = os.path.join(BENCH_DIR, ".cache", "models")
 
@@ -70,15 +70,18 @@ def unflatten(flat: Dict[str, np.ndarray], prefix: str):
     return tree
 
 
-def pipeline_config(config: dict):
-    """The program's pipeline configuration the file names
-    (``MULTISCOPE_PIPELINE``, or its ``.reduced()`` CPU size for tests),
-    checked against the file: every width the file states must be the
-    one the program runs."""
-    from repro.configs.multiscope import MULTISCOPE_PIPELINE
-    pipes = {"MULTISCOPE_PIPELINE": MULTISCOPE_PIPELINE,
-             "MULTISCOPE_PIPELINE.reduced": MULTISCOPE_PIPELINE.reduced()}
-    cfg = pipes[config["pipeline"]]
+def pipeline_config(config: dict, root: str = ROOT):
+    """The program's pipeline configuration the file names: a name in
+    ``repro.configs.multiscope`` (``MULTISCOPE_PIPELINE``), with a
+    ``.reduced`` suffix its ``.reduced()`` CPU size for tests; checked
+    against the file: every width the file states must be the one the
+    program runs (the detector's through its family's
+    ``program_widths``)."""
+    from repro.configs import multiscope
+    name = config["pipeline"]
+    cfg = getattr(multiscope, name.removesuffix(".reduced"))
+    if name.endswith(".reduced"):
+        cfg = cfg.reduced()
     det, prox, trk, win = (config["detector"], config["proxy"],
                            config["tracker"], config["windows"])
     checks = {
@@ -99,11 +102,8 @@ def pipeline_config(config: dict):
                                 win["max_windows"]),
         "detector.max_dets": (cfg.detector.max_dets, det["max_dets"]),
     }
-    from repro.core.detector import ARCHS, STRIDE
-    chans, extras = ARCHS[det["arch"]]
-    checks["detector.channels"] = (list(chans), det["channels"])
-    checks["detector.extra_convs"] = (list(extras), det["extra_convs"])
-    checks["detector.stride_px"] = (STRIDE, det["stride_px"])
+    checks.update(find_family(det["family"], root).program
+                  .program_widths(det))
     theta = config["theta"]
     checks["theta.det_res in menu"] = (
         True, tuple(theta["det_res"]) in cfg.detector.resolutions)
@@ -113,7 +113,7 @@ def pipeline_config(config: dict):
     bad = {k: v for k, v in checks.items() if v[0] != v[1]}
     if bad:
         raise ValueError(f"configuration {config['name']} differs from "
-                         f"the program's MULTISCOPE_PIPELINE: {bad}")
+                         f"the program's {config['pipeline']}: {bad}")
     return cfg
 
 
@@ -122,10 +122,11 @@ def cache_paths(config_name: str, profile: str) -> Tuple[str, str]:
     return base + ".npz", base + ".json"
 
 
-def build(config: dict, profile: str, log=lambda *a: None) -> System:
+def build(config: dict, profile: str, log=lambda *a: None,
+          root: str = ROOT) -> System:
     """Load the configuration's bank for ``profile`` from the cache, or
     train it there."""
-    cfg = pipeline_config(config)
+    cfg = pipeline_config(config, root)
     npz, meta_path = cache_paths(config["name"], profile)
     trained = False
     if os.path.exists(npz) and os.path.exists(meta_path):

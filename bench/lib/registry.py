@@ -9,8 +9,15 @@ or per-layer metric sits in a file of its own under ``bench/``:
                                     its driver
     bench/entries/<entry>.py        one entry driver
     bench/metrics/<metric>.py       one per-layer metric's reader
+    bench/reference/detectors/<family>.py
+                                    one detector family's plain
+                                    reference: ``forward`` and
+                                    ``candidates``
+    bench/lib/detectors/<family>.py the same family's program side:
+                                    ``program_widths`` and ``work``
 
-so a later change adds a cell, a mix or a metric by adding files and
+so a later change adds a cell, a mix, a metric or a detector family
+(named by ``detector.family`` in a configuration) by adding files and
 entries, never by editing this module.
 """
 from __future__ import annotations
@@ -21,7 +28,7 @@ import os
 import sys
 from dataclasses import dataclass, field
 from types import ModuleType
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -61,6 +68,10 @@ class Cell:
     @property
     def entry(self) -> str:
         return self.traffic["entry"]
+
+    @property
+    def root(self) -> str:
+        return os.path.dirname(self.bench_dir)
 
 
 def benchmark(root: str = ROOT) -> dict:
@@ -104,6 +115,22 @@ def find_entry(name: str, root: str = ROOT) -> ModuleType:
 def find_metric(name: str, root: str = ROOT) -> ModuleType:
     return load_module(os.path.join(root, "bench", "metrics",
                                     f"{name}.py"))
+
+
+class Family(NamedTuple):
+    """One detector family's two files, as modules."""
+    reference: ModuleType        # forward, candidates
+    program: ModuleType          # program_widths, work
+
+
+def find_family(name: str, root: str = ROOT) -> Family:
+    """The detector family ``name``; a family without both files is an
+    error (``FileNotFoundError``)."""
+    bench = os.path.join(root, "bench")
+    return Family(
+        load_module(os.path.join(bench, "reference", "detectors",
+                                 f"{name}.py")),
+        load_module(os.path.join(bench, "lib", "detectors", f"{name}.py")))
 
 
 def peaks(device_kind: str, root: str = ROOT) -> Dict[str, float]:

@@ -14,7 +14,7 @@ def read(ctx):
     if t <= 0:
         return None
     ops, byt = detector_work(ctx.config, ctx.theta, ctx.sizes_cells,
-                             ctx.device_counters)
+                             ctx.device_counters, ctx.cell.root)
     least = max(ops / ctx.peaks["flops_per_s"],
                 byt / ctx.peaks["hbm_bytes_per_s"])
     return 100.0 * least / t

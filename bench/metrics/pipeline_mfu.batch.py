@@ -10,7 +10,7 @@ def read(ctx):
     if not ctx.device or ctx.device["window_s"] <= 0:
         return None
     ops = detector_work(ctx.config, ctx.theta, ctx.sizes_cells,
-                        ctx.device_counters)[0]
+                        ctx.device_counters, ctx.cell.root)[0]
     ops += proxy_work(ctx.config, ctx.theta, ctx.device_counters)[0]
     chips = ctx.cell.chips
     return 100.0 * ops / (ctx.device["window_s"] * chips

@@ -7,7 +7,9 @@ For every checked stream (a clip run) the comparison reads, frame by frame:
               program's window plan leaves uncovered;
   det_gap_p99 the 99th percentile, over every detection of the run,
               of the detection decision gap in logits, the reference
-              run on the program's own windows (``detect.frame_gaps``):
+              (the configuration's detector family: its ``forward``
+              and ``candidates``, ``bench/reference/detectors``) run on
+              the program's own windows (``detect.frame_gaps``):
               a program detection's gap to its reference candidate, or
               a reference detection's margin where the program lacks
               it.  (The largest gap, ``det_gap_max``, is reported beside
@@ -37,6 +39,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from bench.lib.registry import ROOT, find_family
 from bench.reference import detect, nets, plan, track
 
 NUMBERS = ("proxy_gap", "det_gap_p99", "box_gap_px", "track_gap",
@@ -64,8 +67,10 @@ def _sizes_order(wins):
 
 def check_stream(s: Stream, weights: Dict[str, np.ndarray], config: dict,
                  theta: dict, frame_fn: Callable,
-                 control: Optional[dict] = None) -> Dict[str, float]:
+                 control: Optional[dict] = None,
+                 root: str = ROOT) -> Dict[str, float]:
     det_cfg, trk_cfg = config["detector"], config["tracker"]
+    family = find_family(det_cfg["family"], root).reference
     W, H = theta["det_res"]
     cell_px = int(det_cfg["cell_px"])
     grid = (W // cell_px, H // cell_px)
@@ -74,8 +79,6 @@ def check_stream(s: Stream, weights: Dict[str, np.ndarray], config: dict,
     levels = int(np.log2(config["proxy"]["cell"]))
     pd, pp = nets.take(weights, "detector"), nets.take(weights, "proxy")
     pt = nets.take(weights, "tracker")
-    chans = tuple(det_cfg["channels"])
-    extras = tuple(det_cfg["extra_convs"])
     cop = None if control is None else control["conv_operands"]
     hop = None if control is None else control["host_operands"]
     frames = np.stack([frame_fn(s.clip, f) for f in s.frame_ids]) \
@@ -120,26 +123,28 @@ def check_stream(s: Stream, weights: Dict[str, np.ndarray], config: dict,
         crops = np.stack([frames[k, y * cell_px:y * cell_px + ph,
                                  x * cell_px:x * cell_px + pw]
                           for k, (x, y, _) in items])
-        fn = functools.partial(nets.detector, pd, channels=chans,
-                               extra_convs=extras)
-        sc, bx = nets.batched(fn, crops, BATCH)
+        fn = functools.partial(family.forward, pd, det_cfg=det_cfg)
+        ref_out = nets.batched(fn, crops, BATCH)
         if control:
-            csc, cbx = nets.batched(functools.partial(fn, operands=cop),
-                                    crops, BATCH)
+            ctl_out = nets.batched(functools.partial(fn, operands=cop),
+                                   crops, BATCH)
         for i, (k, (x, y, _)) in enumerate(items):
             origin = (x * cell_px / W, y * cell_px / H)
             results[(k, x, y, size)] = dict(
-                ref=(sc[i], bx[i]), origin=origin, scale=(pw / W, ph / H),
-                ctl=(csc[i], cbx[i]) if control else None)
+                ref=tuple(o[i] for o in ref_out), origin=origin,
+                scale=(pw / W, ph / H),
+                ctl=tuple(o[i] for o in ctl_out) if control else None)
     for k, wins in enumerate(s.windows):
         full = len(wins) == 1 and tuple(wins[0][2]) == grid
         ref = detect.FrameDetections(conf, nms_iou, max_dets)
         ctl = detect.FrameDetections(conf, nms_iou, max_dets)
         for x, y, size in _sizes_order(wins):
             r = results[(k, x, y, tuple(size))]
-            ref.add_window(*r["ref"], r["origin"], r["scale"])
+            ref.add_window(family.candidates(r["ref"], ref.lo, r["origin"],
+                                             r["scale"], det_cfg))
             if control:
-                ctl.add_window(*r["ctl"], r["origin"], r["scale"])
+                ctl.add_window(family.candidates(
+                    r["ctl"], ctl.lo, r["origin"], r["scale"], det_cfg))
         ref.finish(merge=not full)
         g, b, w = detect.frame_gaps(s.dets[k], ref, nms_iou, W, H)
         note("program", k, g, w)
@@ -199,7 +204,8 @@ def gap_quantile(gaps: Sequence[float], q: float) -> float:
 
 
 def check(streams: Sequence[Stream], weights, config, theta, frame_fn,
-          control: Optional[dict] = None) -> Dict[str, Dict[str, float]]:
+          control: Optional[dict] = None,
+          root: str = ROOT) -> Dict[str, Dict[str, float]]:
     """Each number over the streams: the largest reading, and the
     detection gap's quantile over every detection of every stream.
     Each side also carries ``det_gap_max``, ``det_gaps`` (count and
@@ -209,7 +215,8 @@ def check(streams: Sequence[Stream], weights, config, theta, frame_fn,
     gaps: Dict[str, list] = {}
     worst: Dict[str, dict] = {}
     for s in streams:
-        res = check_stream(s, weights, config, theta, frame_fn, control)
+        res = check_stream(s, weights, config, theta, frame_fn, control,
+                           root)
         for side, vals in res.items():
             a = agg.setdefault(side, {k: 0.0 for k in NUMBERS})
             gaps.setdefault(side, []).extend(vals.pop("_gaps"))

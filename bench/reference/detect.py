@@ -1,9 +1,9 @@
-"""Plain decoding, non-maximum suppression and the detection comparison.
+"""Plain non-maximum suppression and the detection comparison.
 
-A detector cell fires when its score (the sigmoid of its objectness
-logit) exceeds the confidence.  Its box is its cell plus the regressed
-centre offset (clipped to [0, 1]) and log-size (clipped to [-5, 5], in
-cell units), placed into the frame by the window's origin and scale.
+Decoding a window's head outputs into candidate boxes is the detector
+family's job (``candidates`` in ``bench/reference/detectors/<family>.py``):
+rows of [cx, cy, w, h, logit] in frame units.  A candidate fires when
+its score (the sigmoid of its logit) exceeds the confidence.
 Per window the candidates are ranked by score, at most ``4 * max_dets``
 are kept, greedy NMS at ``nms_iou`` runs and ``max_dets`` survive; a
 frame planned as several windows merges them and runs NMS once more.
@@ -71,21 +71,6 @@ def nms(dets: np.ndarray, thr: float):
     return d, keep, by
 
 
-def decode(logits: np.ndarray, boxes: np.ndarray, lo: float,
-           origin, scale) -> np.ndarray:
-    """Every cell whose logit exceeds ``lo`` -> (n, 5) world boxes with
-    the logit in column 4."""
-    hc, wc = logits.shape
-    ii, jj = np.nonzero(logits > lo)
-    lg = logits[ii, jj].astype(np.float64)
-    bx = boxes[ii, jj].astype(np.float64)
-    cx = origin[0] + (jj + np.clip(bx[:, 0], 0, 1)) / wc * scale[0]
-    cy = origin[1] + (ii + np.clip(bx[:, 1], 0, 1)) / hc * scale[1]
-    w = np.exp(np.clip(bx[:, 2], -5, 5)) / wc * scale[0]
-    h = np.exp(np.clip(bx[:, 3], -5, 5)) / hc * scale[1]
-    return np.stack([cx, cy, w, h, lg], axis=1).reshape(-1, 5)
-
-
 class FrameDetections:
     """The reference's detections of one frame under a given window
     plan, with every candidate box and the decision that dropped it.
@@ -93,6 +78,7 @@ class FrameDetections:
 
     def __init__(self, conf: float, nms_iou: float, max_dets: int):
         self.lconf = float(np.log(conf) - np.log1p(-conf))
+        self.lo = self.lconf - NEAR            # the candidates to decode
         self.nms_iou, self.max_dets = nms_iou, max_dets
         self.rows: List[np.ndarray] = []       # candidate boxes
         self.status: List[int] = []
@@ -106,8 +92,9 @@ class FrameDetections:
         self.supp.append(np.full(5, np.nan) if supp is None else supp)
         return len(self.rows) - 1
 
-    def add_window(self, logits, boxes, origin, scale) -> None:
-        cand = decode(logits, boxes, self.lconf - NEAR, origin, scale)
+    def add_window(self, cand: np.ndarray) -> None:
+        """One window's decoded candidates, (n, 5) rows whose logit
+        exceeds ``lo``."""
         for row in cand[cand[:, 4] <= self.lconf]:
             self._add(row, BELOW)
         fired = cand[cand[:, 4] > self.lconf]

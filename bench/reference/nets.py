@@ -1,4 +1,6 @@
-"""Plain forward passes of the three networks, in JAX at full precision.
+"""Plain forward passes of the proxy and the tracker's crop network, and
+the convolution every reference network (the detector families' too,
+``bench/reference/detectors``) is built from, in JAX at full precision.
 
 Straight from the architecture the configuration file states: strided
 3x3 convolutions with 'SAME' padding and ReLU, a 1x1 head, sigmoid
@@ -13,7 +15,7 @@ operands and float32 accumulation: the control of the comparison.
 from __future__ import annotations
 
 import functools
-from typing import Dict, Optional, Sequence
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,23 +42,6 @@ def take(weights: Dict[str, np.ndarray], prefix: str) -> Dict[str, jnp.ndarray]:
     n = len(prefix) + 1
     return {k[n:]: jnp.asarray(v, jnp.float32) for k, v in weights.items()
             if k.startswith(prefix + "/")}
-
-
-@functools.partial(jax.jit, static_argnames=("channels", "extra_convs",
-                                             "operands"))
-def detector(p, frames, channels: Sequence[int], extra_convs: Sequence[int],
-             operands: Optional[str] = None):
-    """frames (B, H, W, 3) -> (objectness logits (B, H/16, W/16),
-    boxes (..., 4))."""
-    x = frames
-    for i in range(len(channels)):
-        x = jax.nn.relu(conv(x, p[f"block{i}_down/w"], p[f"block{i}_down/b"],
-                             2, operands))
-        for j in range(extra_convs[i]):
-            x = jax.nn.relu(conv(x, p[f"block{i}_conv{j}/w"],
-                                 p[f"block{i}_conv{j}/b"], 1, operands))
-    out = conv(x, p["head/w"], p["head/b"], 1, operands)
-    return out[..., 0], out[..., 1:]
 
 
 @functools.partial(jax.jit, static_argnames=("levels", "operands"))

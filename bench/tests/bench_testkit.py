@@ -28,7 +28,8 @@ TINY_CONFIG = {
     "source": "MULTISCOPE_PIPELINE.reduced() of src/repro/configs/multiscope.py",
     "pipeline": "MULTISCOPE_PIPELINE.reduced",
     "frame_size": [256, 160],
-    "detector": {"arch": "ssd-lite", "channels": [12, 24, 48, 96],
+    "detector": {"family": "ssd", "arch": "ssd-lite",
+                 "channels": [12, 24, 48, 96],
                  "extra_convs": [0, 0, 0, 0], "stride_px": 16,
                  "cell_px": 16, "max_dets": 24, "nms_iou": 0.45},
     "proxy": {"cell": 8, "base_channels": 4},

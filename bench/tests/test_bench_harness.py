@@ -284,26 +284,57 @@ def test_stage_metrics_read_from_the_unprofiled_window(tiny, monkeypatch):
 
 # -- operations counted from shapes ------------------------------------------
 
-@pytest.mark.parametrize("hw", [(64, 96), (35, 50)])
-def test_flop_counts_against_xla_cost_analysis(hw):
-    """The counts leave out only the bias and activation, so they sit
-    just under XLA's own count and never above it."""
+FAMILIES = sorted(f[:-3] for f in os.listdir(os.path.join(
+    registry.BENCH_DIR, "reference", "detectors")) if f.endswith(".py"))
+HW = [(64, 96), (35, 50)]
+
+
+def _xla_flops(fn, p, h, w):
     import jax
     import jax.numpy as jnp
+    x = jax.ShapeDtypeStruct((1, h, w, 3), jnp.float32)
+    ca = jax.jit(fn).lower(p, x).compile().cost_analysis()
+    return (ca[0] if isinstance(ca, list) else ca)["flops"]
+
+
+def _detector_configs(family):
+    """The detector blocks of every configuration of ``family``: the
+    benchmark's configuration files and the test kit's."""
+    blocks = [registry.load_json(os.path.join(registry.BENCH_DIR, "configs",
+                                              f))["detector"]
+              for f in sorted(os.listdir(os.path.join(registry.BENCH_DIR,
+                                                      "configs")))]
+    blocks.append(kit.TINY_CONFIG["detector"])
+    return [d for d in blocks if d["family"] == family]
+
+
+@pytest.mark.parametrize("hw", HW)
+@pytest.mark.parametrize("family", FAMILIES)
+def test_flop_counts_against_xla_cost_analysis(family, hw):
+    """A family's ``work`` leaves out only the bias and activation, so
+    it sits just under XLA's own count of the family's reference
+    ``forward`` and never above it."""
+    from bench.lib import models
+    from bench.reference import nets
+    from repro.core.detector import init_detector
+    fam = registry.find_family(family)
+    h, w = hw
+    dets = _detector_configs(family)
+    assert dets, f"no configuration uses the family {family!r}"
+    for d in dets:
+        p = nets.take(models.flatten(init_detector(d["arch"], 0),
+                                     "detector"), "detector")
+        xla = _xla_flops(lambda p, a: fam.reference.forward(p, a, d), p, h, w)
+        mine = fam.program.work(d, h, w)[0]
+        assert 0.97 * xla <= mine <= xla, d["arch"]
+
+
+@pytest.mark.parametrize("hw", HW)
+def test_proxy_flop_counts_against_xla_cost_analysis(hw):
     from bench.lib import flops
-    from repro.core.detector import detector_raw, init_detector
     from repro.core.proxy import init_proxy, proxy_features
     h, w = hw
-    x = jax.ShapeDtypeStruct((1, h, w, 3), jnp.float32)
-
-    def xla(fn, p):
-        ca = jax.jit(fn).lower(p, x).compile().cost_analysis()
-        return (ca[0] if isinstance(ca, list) else ca)["flops"]
-    det = xla(lambda p, a: detector_raw(p, a, "ssd-deep"),
-              init_detector("ssd-deep", 0))
-    mine = flops.conv_flops(flops.detector_layers([16, 32, 64, 128],
-                                                  [1, 1, 1, 1]), h, w)
-    assert 0.97 * det <= mine <= det
-    prox = xla(lambda p, a: proxy_features(p, a, 32), init_proxy(32, 8, 0))
+    prox = _xla_flops(lambda p, a: proxy_features(p, a, 32),
+                      init_proxy(32, 8, 0), h, w)
     mine = flops.conv_flops(flops.proxy_layers(32, 8)[:-1], h, w)
     assert 0.97 * prox <= mine <= prox
