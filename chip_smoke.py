@@ -150,7 +150,6 @@ def build_system(cfg, *, n_clips: int = 4, n_frames: int = 64,
     setup_s = time.perf_counter() - t0
     emit("setup", setup_cold_s=setup_s, frame=[W, H], proxy=list(res),
          detector=det.arch, detector_stride_px=STRIDE, cell_px=pl.CELL_PX,
-         config_stride_px=cfg.detector.stride,
          window_sizes_cells=[list(s) for s in bank.sizes_cells],
          det_steps=det_steps, det_loss_last=float(np.mean(losses[-10:])),
          f1_by_conf=f1, det_conf=conf,

@@ -30,14 +30,14 @@ class ProxyConfig:
 
 @dataclass(frozen=True)
 class DetectorConfig:
-    """Single-shot anchor-free detector.  Two registered architectures of
-    different depths preserve the paper's arch-choice tuning dimension
-    (YOLOv3 vs Mask R-CNN in the paper)."""
+    """The detector's architecture menu (``repro.core.detector``): the
+    tuner's arch-choice dimension (YOLOv3 vs Mask R-CNN in the paper).
+    ``MULTISCOPE_PIPELINE`` offers the ssd family's two depths;
+    ``YOLOV3_PIPELINE`` the paper's YOLOv3."""
     archs: Tuple[str, ...] = ("ssd-lite", "ssd-deep")
     resolutions: Tuple[Tuple[int, int], ...] = (
         (960, 544), (832, 480), (704, 416), (608, 352), (512, 288),
         (448, 256), (384, 224), (320, 192))
-    stride: int = 32                     # one prediction cell per 32x32 px
     confidences: Tuple[float, ...] = (0.25, 0.4, 0.55, 0.7)
     max_dets: int = 64                   # static shape: detections per frame
 
@@ -101,10 +101,11 @@ class PipelineConfig:
                 resolutions=((64, 40), (48, 32), (32, 24)),
                 thresholds=(0.1, 0.3, 0.5, 0.7)),
             detector=DetectorConfig(
-                archs=("ssd-lite", "ssd-deep"),
+                archs=tuple(REDUCED_ARCHS.get(a, a)
+                            for a in self.detector.archs),
                 resolutions=((256, 160), (208, 128), (160, 96),
                              (128, 80)),
-                stride=16, max_dets=24,
+                max_dets=24,
                 confidences=(0.4, 0.55, 0.7)),
             tracker=TrackerConfig(gaps=(1, 2, 4, 8), embed_dim=16,
                                   rnn_dim=32, match_hidden=32, crop=8,
@@ -117,7 +118,15 @@ class PipelineConfig:
         )
 
 
+# an architecture's CPU-size stand-in in ``PipelineConfig.reduced()``
+REDUCED_ARCHS: Dict[str, str] = {"yolov3": "yolov3-r16"}
+
 MULTISCOPE_PIPELINE = PipelineConfig()
+
+# the paper's own detector, YOLOv3 on Darknet-53 at darknet's published
+# widths, as the only architecture of the menu (a pipeline of its own,
+# so the ssd pipeline's tuner and chip smoke test never train it)
+YOLOV3_PIPELINE = PipelineConfig(detector=DetectorConfig(archs=("yolov3",)))
 
 # Registered as a ModelConfig shell so `--arch multiscope` resolves through
 # the same registry; pipeline details live in PipelineConfig above.

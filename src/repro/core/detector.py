@@ -1,31 +1,39 @@
-"""Single-shot anchor-free object detector (the pipeline's expensive model).
+"""Single-shot object detectors (the pipeline's expensive model).
 
 The paper treats the detector as a pluggable black box with an
 (architecture, input resolution) menu (YOLOv3 / Mask R-CNN at several
-resolutions); this repo registers two architectures of different depths,
-``ssd-lite`` and ``ssd-deep``, preserving the tuner's arch-choice
-dimension.
+resolutions).  Two families are registered in ``FAMILIES``, each an
+architecture menu the tuner can choose from and each bringing its
+parameters, device forward, host decode and training (``Family``):
 
-Design: strided conv backbone to stride ``S`` (16), then a 1x1 head
-predicting per cell [objectness, dx, dy, log w, log h].  A cell is
-positive when an object center falls inside it; boxes are regressed
-relative to the cell (center offset in [0,1]) and the frame (log-size).
-The same network applies to full frames AND to the proxy-selected windows
-(any HxW divisible by the stride) — one jit specialization per input
-size and power-of-two batch bucket, which is exactly the paper's
-"initialize the detector at each of the k fixed window sizes" with the
-chunked engine's cross-frame batching layered on top.
+* ``ssd`` (``ssd-lite``, ``ssd-deep``, widths in ``ARCHS``): a strided
+  conv backbone to stride ``STRIDE`` (16), then a 1x1 head predicting
+  per cell [objectness, dx, dy, log w, log h].  A cell is positive when
+  an object center falls inside it; boxes are regressed relative to the
+  cell (center offset in [0,1]) and the frame (log-size).
+* ``yolov3`` (``yolov3``, the paper's own detector at darknet's
+  published widths, and ``yolov3-r16`` for CPU tests): Darknet-53 with
+  three anchor heads at strides 32, 16 and 8, batch norm folded into
+  the convs when a ``Detector`` is built (``repro.core.yolov3``).
+
+Either network applies to full frames AND to the proxy-selected windows
+(any HxW divisible by 16; YOLOv3 letterboxes to 32) — one jit
+specialization per input size and power-of-two batch bucket, which is
+exactly the paper's "initialize the detector at each of the k fixed
+window sizes" with the chunked engine's cross-frame batching layered on
+top.  Every family's device forward runs inside ``_detect_scores``.
 """
 from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import yolov3
 from repro.models.common import ParamBuilder, build
 from repro.obs.trace import NO_SPAN, TRACER
 
@@ -53,7 +61,7 @@ def _apply_conv(p, x, stride: int = 1):
     return y + p["b"]
 
 
-def def_detector(pb: ParamBuilder, arch: str) -> None:
+def _def_ssd(pb: ParamBuilder, arch: str) -> None:
     chans, extras = ARCHS[arch]
     cin = 3
     for i, (c, extra) in enumerate(zip(chans, extras)):
@@ -62,11 +70,6 @@ def def_detector(pb: ParamBuilder, arch: str) -> None:
             _conv(pb, f"block{i}_conv{j}", c, c)
         cin = c
     _conv(pb, "head", cin, 5, k=1)
-
-
-def init_detector(arch: str, seed: int = 0):
-    return build(functools.partial(def_detector, arch=arch), "init",
-                 seed=seed)
 
 
 @functools.partial(jax.jit, static_argnames=("arch",))
@@ -120,12 +123,6 @@ def make_targets(boxes_list: List[np.ndarray], hc: int, wc: int
     return obj, box
 
 
-@functools.partial(jax.jit, static_argnames=("arch",))
-def _detect_scores(params, frames, arch: str):
-    out = detector_raw(params, frames, arch)
-    return jax.nn.sigmoid(out[..., 0]), out[..., 1:]
-
-
 def decode_detections(scores: np.ndarray, boxes: np.ndarray,
                       conf: float, origin: Tuple[float, float] = (0.0, 0.0),
                       scale: Tuple[float, float] = (1.0, 1.0),
@@ -147,6 +144,105 @@ def decode_detections(scores: np.ndarray, boxes: np.ndarray,
     h = np.exp(np.clip(bx[:, 3], -5, 5)) / hc * scale[1]
     dets = np.stack([cx, cy, w, h, sc], axis=1).astype(np.float32)
     return nms(dets)[:max_dets]
+
+
+def _ssd_scores(params, frames, arch: str):
+    """(B, Hc, Wc) objectness and (B, Hc, Wc, 4) cell-relative boxes."""
+    out = detector_raw(params, frames, arch)
+    return jax.nn.sigmoid(out[..., 0]), out[..., 1:]
+
+
+def _ssd_decode(scores, boxes, conf, hw, arch, origin, scale, max_dets):
+    return decode_detections(scores, boxes, conf, origin=origin,
+                             scale=scale, max_dets=max_dets)
+
+
+def _ssd_train(arch: str, params, sample, steps: int, lr: float):
+    # train_models imports this module, so its generic fit comes late
+    from repro.core.train_models import _fit
+
+    def batches():
+        for step in range(steps):
+            frames, boxes = sample(step)
+            obj, box = make_targets(boxes, frames.shape[1] // STRIDE,
+                                    frames.shape[2] // STRIDE)
+            yield (jnp.asarray(frames), jnp.asarray(obj), jnp.asarray(box))
+
+    loss_fn = lambda p, f, o, b: detector_loss(p, f, o, b, arch)  # noqa
+    return _fit(loss_fn, params, batches(), lr=lr)
+
+
+def _yolov3_decode(scores, boxes, conf, hw, arch, origin, scale, max_dets):
+    cand = yolov3.decode(scores, boxes, conf, hw, arch, origin=origin,
+                         scale=scale, max_dets=max_dets)
+    return nms(cand)[:max_dets]
+
+
+@dataclass(frozen=True)
+class Family:
+    """What the program needs of one detector family.  ``scores`` runs
+    inside ``_detect_scores``; the rest on the host."""
+    archs: Mapping[str, object]   # architecture -> its widths
+    def_params: Callable          # (ParamBuilder, arch)
+    fold: Callable                # (stored params, arch) -> forward params
+    scores: Callable              # (forward params, frames, arch)
+    #                               -> device (scores, boxes) of a batch
+    decode: Callable              # (scores, boxes, conf, (h, w), arch,
+    #                               origin, scale, max_dets) of one window
+    #                               -> (n, 5) detections after NMS
+    counters: Callable            # (scores of a batch, conf, (h, w), arch)
+    #                               -> the decode span's family counters
+    train: Callable               # (arch, params, sample, steps, lr)
+    #                               -> (params, losses)
+    max_batch: Optional[int] = None   # frames one device call holds
+
+
+FAMILIES: Dict[str, Family] = {
+    "ssd": Family(ARCHS, _def_ssd, lambda params, arch: params, _ssd_scores,
+                  _ssd_decode, lambda scores, conf, hw, arch: {},
+                  _ssd_train),
+    "yolov3": Family(yolov3.SPECS, yolov3.def_params, yolov3.fold,
+                     yolov3.scores, _yolov3_decode, yolov3.counters,
+                     yolov3.train, max_batch=yolov3.MAX_BATCH),
+}
+
+
+def family_name(arch: str) -> str:
+    """The name of the family an architecture belongs to."""
+    for name, fam in FAMILIES.items():
+        if arch in fam.archs:
+            return name
+    raise KeyError(f"unknown detector architecture {arch!r} (have "
+                   f"{sorted(a for f in FAMILIES.values() for a in f.archs)})")
+
+
+def family(arch: str) -> Family:
+    """The family an architecture belongs to."""
+    return FAMILIES[family_name(arch)]
+
+
+def def_detector(pb: ParamBuilder, arch: str) -> None:
+    family(arch).def_params(pb, arch)
+
+
+def init_detector(arch: str, seed: int = 0):
+    return build(functools.partial(def_detector, arch=arch), "init",
+                 seed=seed)
+
+
+def fold_params(params, arch: str):
+    """The parameters the forward runs on: YOLOv3's batch norm folded
+    into its convs; an ssd tree as it is."""
+    return family(arch).fold(params, arch)
+
+
+@functools.partial(jax.jit, static_argnames=("arch",))
+def _detect_scores(params, frames, arch: str):
+    """Device forward of every family -> (scores, boxes).  ssd: (B, Hc,
+    Wc) objectness and (B, Hc, Wc, 4) cell-relative boxes; yolov3
+    (folded parameters): (B, N) class-conditioned scores and (B, N, 4)
+    raw anchor regressions over the three grids."""
+    return family(arch).scores(params, frames, arch)
 
 
 def nms(dets: np.ndarray, iou_thresh: float = 0.45) -> np.ndarray:
@@ -224,8 +320,11 @@ class Detector:
 
     def __init__(self, arch: str, params=None, seed: int = 0):
         self.arch = arch
+        self.family = family(arch)
         self.params = params if params is not None else init_detector(
             arch, seed)
+        # what the forward runs on: YOLOv3's batch norm folded, once
+        self.forward_params = fold_params(self.params, arch)
         # dispatch counter: the track store's re-ingest guarantee
         # ("zero detector calls on a warm split") is asserted against it.
         # Kept a plain per-instance int (benches reset it directly); each
@@ -254,27 +353,40 @@ class Detector:
                 x = jnp.asarray(frames)
         with TRACER.span("detect.wait", "detect") \
                 if TRACER.enabled else NO_SPAN:
-            scores, boxes = _detect_scores(self.params, x, self.arch)
+            scores, boxes = self._scores(x)
             del x       # an uploaded batch is freed with its computation
             scores = np.asarray(scores)
-            hit = (scores[:n] > conf).any(axis=(1, 2))
+            hit = (scores[:n] > conf).any(axis=tuple(range(1, scores.ndim)))
             boxes = np.asarray(boxes) if hit.any() else None
+            if TRACER.enabled:
+                TRACER.add("d2h_bytes", scores.nbytes + (
+                    0 if boxes is None else boxes.nbytes))
         with TRACER.span("detect.decode", "detect", args={"windows": n}) \
                 if TRACER.enabled else NO_SPAN as sp:
             empty = np.zeros((0, 5), np.float32)
-            out = []
-            for b in range(n):
-                if not hit[b]:
-                    out.append(empty)
-                    continue
-                o = origins[b] if origins is not None else (0.0, 0.0)
-                s = scales[b] if scales is not None else (1.0, 1.0)
-                out.append(decode_detections(scores[b], boxes[b], conf,
-                                             origin=o, scale=s,
-                                             max_dets=max_dets))
+            hw = tuple(frames.shape[1:3])
+            out = [self.family.decode(
+                scores[b], boxes[b], conf, hw, self.arch,
+                origins[b] if origins is not None else (0.0, 0.0),
+                scales[b] if scales is not None else (1.0, 1.0), max_dets)
+                if hit[b] else empty for b in range(n)]
             if sp is not None:
                 sp.args["dets"] = sum(len(d) for d in out)
+                sp.args["candidates"] = int((scores[:n] > conf).sum())
+                sp.args.update(self.family.counters(scores[:n], conf, hw,
+                                                    self.arch))
         return out
+
+    def _scores(self, x):
+        """``_detect_scores`` over the batch, in slices of the family's
+        ``max_batch`` where it has one."""
+        step = self.family.max_batch
+        if step is None or x.shape[0] <= step:
+            return _detect_scores(self.forward_params, x, self.arch)
+        parts = [_detect_scores(self.forward_params, x[i:i + step],
+                                self.arch)
+                 for i in range(0, x.shape[0], step)]
+        return tuple(jnp.concatenate(p) for p in zip(*parts))
 
     def detect_batch_bucketed(self, frames: np.ndarray, conf: float,
                               origins=None, scales=None,

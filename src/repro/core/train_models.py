@@ -13,7 +13,6 @@ import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import detector as det_mod
@@ -44,30 +43,28 @@ def train_detector(arch: str, clips: Sequence[Clip],
                    resolutions: Sequence[Tuple[int, int]],
                    steps: int = 240, batch: int = 8, seed: int = 0,
                    lr: float = 3e-3) -> det_mod.Detector:
-    """Multi-resolution detector pre-training on synthetic GT boxes.
-    Frames come through the pipeline's render cache, so a step renders
-    only frames no earlier step drew."""
+    """Multi-resolution detector pre-training on synthetic GT boxes,
+    through the architecture's family (``detector.FAMILIES``: its
+    targets, loss and optimiser loop).  Frames come through the
+    pipeline's render cache, so a step renders only frames no earlier
+    step drew."""
     from repro.core.pipeline import render_frame
     params = det_mod.init_detector(arch, seed)
     rng = np.random.default_rng(seed)
-    S = det_mod.STRIDE
 
-    def batches():
-        for step in range(steps):
-            W, H = resolutions[step % len(resolutions)]
-            hc, wc = H // S, W // S
-            frames, boxes = [], []
-            for _ in range(batch):
-                clip = clips[rng.integers(len(clips))]
-                f = int(rng.integers(clip.n_frames))
-                frames.append(render_frame(clip, f, W, H)[0])
-                boxes.append(clip.boxes_at(f))
-            obj, box = det_mod.make_targets(boxes, hc, wc)
-            yield (jnp.asarray(np.stack(frames)), jnp.asarray(obj),
-                   jnp.asarray(box))
+    def sample(step):
+        """One batch: (frames (B, H, W, 3), their ground-truth boxes)."""
+        W, H = resolutions[step % len(resolutions)]
+        frames, boxes = [], []
+        for _ in range(batch):
+            clip = clips[rng.integers(len(clips))]
+            f = int(rng.integers(clip.n_frames))
+            frames.append(render_frame(clip, f, W, H)[0])
+            boxes.append(clip.boxes_at(f))
+        return np.stack(frames), boxes
 
-    loss_fn = lambda p, f, o, b: det_mod.detector_loss(p, f, o, b, arch)  # noqa
-    params, losses = _fit(loss_fn, params, batches(), lr=lr)
+    params, losses = det_mod.family(arch).train(arch, params, sample, steps,
+                                                lr)
     return det_mod.Detector(arch, params), losses
 
 

@@ -163,3 +163,15 @@ def test_detector_forward(tpu, arch):
     hlo = _hlo(lambda p, f: _detect_scores(p, f, arch), params,
                tpu((CHUNK, H, W, 3)))
     assert "convolution" in hlo
+
+
+@pytest.mark.parametrize("hw", [(H, W), WINDOWS[1][::-1]])
+def test_yolov3_forward(tpu, hw):
+    """YOLOv3 at darknet's widths on a 16-frame chunk of full frames and
+    of 480x272 windows (letterboxed to 480x288 inside the forward)."""
+    from repro.core.detector import _detect_scores, fold_params, init_detector
+    params = jax.tree.map(lambda a: tpu(a.shape, a.dtype), jax.eval_shape(
+        lambda: fold_params(init_detector("yolov3"), "yolov3")))
+    hlo = _hlo(lambda p, f: _detect_scores(p, f, "yolov3"), params,
+               tpu((CHUNK,) + tuple(hw) + (3,)))
+    assert "convolution" in hlo
