@@ -38,6 +38,9 @@ the decode worker (double buffering: the upload of chunk k+1 overlaps
 chunk k's detector work) or lazily by DETECT, is only ever needed for
 sub-frame window gathers, and is donated back (deleted) as soon as
 DETECT finishes so at most ``prefetch_depth`` device buffers exist.
+DETECT allocates no host batch per dispatch: full-frame windows go up
+as the chunk's own rows, or gathered into a staging buffer that the
+``ClipExecutor`` keeps for each draining thread (``_Staging``).
 
 Sharding attaches at the chunk boundary: chunks are independent through
 DETECT, so stages 1-3 round-robin across ``ExecutorOptions.devices``
@@ -72,7 +75,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.detector import next_bucket, nms, pad_to_bucket
+from repro.core.detector import next_bucket, nms
 from repro.core.pipeline import (CELL_PX, ModelBank, PipelineParams,
                                  RunResult, det_grid, downsample_chunk,
                                  make_sizeset, map_proxy_grid,
@@ -751,6 +754,32 @@ class TrackBroker:
         return K
 
 
+class _Staging:
+    """DETECT's reused host batch for full-frame windows, one per
+    draining thread: a ``ClipExecutor`` hands it to every run, and two
+    threads may drain runs of one executor at once.
+
+    Reuse is safe because ``Detector.detect_batch`` pulls its scores,
+    and boxes where a window fired, before it returns: the computation
+    that read the uploaded batch has finished before the buffer is
+    written again, also on the CPU backend, where ``jnp.asarray`` may
+    alias the numpy buffer."""
+
+    def __init__(self):
+        self._tls = threading.local()
+
+    def buffer(self, shape: Tuple[int, ...]) -> np.ndarray:
+        """The calling thread's float32 buffer, allocated on its first
+        call; with tracing on, the allocation adds its bytes to the
+        innermost span's ``alloc_bytes``."""
+        buf = getattr(self._tls, "buf", None)
+        if buf is None:
+            buf = self._tls.buf = np.empty(shape, np.float32)
+            if TRACER.enabled:
+                TRACER.add("alloc_bytes", buf.nbytes)
+        return buf
+
+
 def _auto_axes(mesh):
     """The same devices and axis names with every axis Auto.  A chunk
     is placed on the mesh and XLA partitions the stages from there;
@@ -773,11 +802,12 @@ class _RunContext:
     knows whether it is running a whole clip or a resumed slice."""
 
     def __init__(self, bank: ModelBank, params: PipelineParams,
-                 clip: Clip, options: ExecutorOptions,
+                 clip: Clip, options: ExecutorOptions, staging: _Staging,
                  device_offset: int = 0,
                  frame_ids: Optional[Sequence[int]] = None,
                  tracker: Optional[object] = None):
         self.bank = bank
+        self.staging = staging
         self.params = params
         self.clip = clip
         self.cfg = bank.cfg
@@ -898,18 +928,44 @@ class _RunContext:
         return jax.default_device(self.device_for(task))
 
     def upload(self, task: ChunkTask):
-        """Pad the chunk to B frames (one gather jit shape) and place it
-        on this chunk's device / mesh sharding.  With tracing on, the
-        calling thread's innermost span records the bytes sent."""
-        padded = np.zeros((self.chunk, self.H, self.W, 3), np.float32)
-        padded[:task.frames.shape[0]] = task.frames
+        """Place the chunk at B frames (one gather jit shape) on this
+        chunk's device / mesh sharding: a whole chunk as DECODE left it
+        (nothing writes it again), a partial one zero-padded into a
+        fresh array.  The device copy outlives the call (window gathers
+        read it until DETECT ends), so it never shares the staging
+        buffer.  With tracing on, the calling thread's innermost span
+        records the bytes sent and any bytes allocated."""
+        host = task.frames
+        if host.shape[0] != self.chunk:
+            host = np.zeros((self.chunk, self.H, self.W, 3), np.float32)
+            host[:task.frames.shape[0]] = task.frames
+            if TRACER.enabled:
+                TRACER.add("alloc_bytes", host.nbytes)
         if TRACER.enabled:
-            TRACER.add("h2d_bytes", padded.nbytes)
+            TRACER.add("h2d_bytes", host.nbytes)
         if self.sharding is not None:
-            return jax.device_put(padded, self.sharding)
+            return jax.device_put(host, self.sharding)
         if len(self.devices) > 1:
-            return jax.device_put(padded, self.device_for(task))
-        return jnp.asarray(padded)
+            return jax.device_put(host, self.device_for(task))
+        return jnp.asarray(host)
+
+    def full_frame_batch(self, frames: np.ndarray, slots: List[int]
+                         ) -> Tuple[np.ndarray, str]:
+        """The detector's host batch for full-frame windows at
+        ``slots``, zero-padded to its bucket, with no fresh batch, and
+        how it was made: ``"direct"``, a leading run of the chunk that
+        fills its bucket, passed as the chunk's own rows; ``"staged"``,
+        any other set, gathered into the staging buffer."""
+        n = len(slots)
+        b = next_bucket(n)
+        if n == b and slots == list(range(n)):
+            return frames[:b], "direct"
+        out = self.staging.buffer(
+            (next_bucket(self.chunk),) + frames.shape[1:])[:b]
+        # mode="raise" would gather through a fresh temporary first
+        np.take(frames, slots, axis=0, out=out[:n], mode="clip")
+        out[n:] = 0
+        return out, "staged"
 
 
 # ---------------------------------------------------------------------------
@@ -1015,10 +1071,13 @@ def stage_detect(ctx: _RunContext, task: ChunkTask) -> ChunkTask:
                                          origins, scales, n)
                 else:
                     # detect_batch uploads the padded batch
-                    with TRACER.span("detect.upload", "detect") \
-                            if TRACER.enabled else NO_SPAN:
-                        stack = pad_to_bucket(
-                            frames[[slot for (slot, _, _, _) in entries]])
+                    with TRACER.span("detect.upload", "detect",
+                                     args={"alloc_bytes": 0}) \
+                            if TRACER.enabled else NO_SPAN as sp:
+                        stack, path = ctx.full_frame_batch(
+                            frames, [slot for (slot, _, _, _) in entries])
+                        if sp is not None:
+                            sp.args["path"] = path
                     dets = detector.detect_batch(
                         stack, ctx.params.det_conf, origins=origins,
                         scales=scales, n_valid=n)
@@ -1498,6 +1557,8 @@ class ClipExecutor:
                 "no shard_map over the chunk's batch axis with a "
                 "per-shard window table.  Leave mesh unset to "
                 "round-robin whole chunks over ExecutorOptions.devices.")
+        # DETECT's host batch, reused by every run of this executor
+        self._staging = _Staging()
         self.stages = dict(DEFAULT_STAGES)
         if stages:
             self.stages.update(stages)
@@ -1527,7 +1588,7 @@ class ClipExecutor:
         explicit frame slice, feeding an existing tracker whose state
         carries across segment runs."""
         ctx = _RunContext(self.bank, self.params, clip, self.options,
-                          device_offset=device_offset,
+                          self._staging, device_offset=device_offset,
                           frame_ids=frame_ids, tracker=tracker)
         handle = self.scheduler.start(ctx, self._tasks(ctx), self.stages)
         return _ActiveRun(ctx, handle)

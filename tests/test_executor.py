@@ -112,6 +112,173 @@ def test_executor_run_clips_matches_per_clip(exec_bank):
     assert total == pytest.approx(sum(r.seconds for r in results))
 
 
+# ---------------------------------------------------------------------------
+# DETECT's host batch: the chunk's own rows or the reused staging buffer
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def thresholds(exec_bank):
+    """Proxy thresholds by plan mix: ``mixed`` plans full frames, sub-
+    frame windows and skips within one chunk, ``sparse`` is the
+    fixture's calibrated threshold, ``dense`` plans every frame full."""
+    bank, clips, res, th = exec_bank
+    W, H = bank.cfg.detector.resolutions[-1]
+    frame, _ = pl.render_frame(clips[0], 0, W, H)
+    s, _ = bank.proxies[res].scores(pl._downsample(frame, res))
+    return {"mixed": float(np.quantile(s, 0.8)), "sparse": th,
+            "dense": float("-inf")}
+
+
+def _full_frame_batches(monkeypatch):
+    """Record every full-frame batch DETECT builds: (chunk rows, slots,
+    path, bucket), after checking that it holds the slots' frames and
+    zeros below them, as a fresh padded gather would."""
+    from repro.core import executor as ex
+    made = []
+    build = ex._RunContext.full_frame_batch
+
+    def spy(ctx, frames, slots):
+        batch, path = build(ctx, frames, slots)
+        n = len(slots)
+        np.testing.assert_array_equal(batch[:n], frames[slots])
+        assert not batch[n:].any()
+        made.append((len(frames), list(slots), path, len(batch)))
+        return batch, path
+    monkeypatch.setattr(ex._RunContext, "full_frame_batch", spy)
+    return made
+
+
+def _per_frame_dets(monkeypatch):
+    """Record each run's per-frame detections, in frame order: the
+    reference's from ``detect_with_windows``, the executor's from its
+    DETECT stage -> {clip_id: [dets]}."""
+    from repro.core import executor as ex
+    ref: dict = {}
+    got: dict = {}
+    detect_one = pl.detect_with_windows
+    current: list = []
+
+    def ref_detect(*args, **kw):
+        dets, windows = detect_one(*args, **kw)
+        current.append(dets)
+        return dets, windows
+
+    def stage(ctx, task):
+        out = ex.stage_detect(ctx, task)
+        got.setdefault(ctx.clip.clip_id, []).extend(out.dets)
+        return out
+    monkeypatch.setattr(pl, "detect_with_windows", ref_detect)
+    monkeypatch.setitem(ex.DEFAULT_STAGES, "detect", stage)
+
+    def reference(bank, params, clip):
+        current.clear()
+        r = pl.run_clip_frames(bank, params, clip)
+        ref[clip.clip_id] = list(current)
+        return r
+    return reference, ref, got
+
+
+def _leading(slots):
+    return slots == list(range(len(slots)))
+
+
+# what each case must exercise, read from the batches DETECT built
+HOST_BATCH_CASES = {
+    # full-frame slots with gaps between them: skipped frames and
+    # sub-frame windows in the same chunk; the staged gather
+    "non_contiguous": lambda made, B: any(
+        not _leading(s) for _, s, _, _ in made),
+    # the clip's last chunk is short (24 frames at B=7 or 16)
+    "partial": lambda made, B: any(rows < B for rows, _, _, _ in made),
+    # a staged bucket smaller than the one before it, with padding
+    # rows where the earlier batch left its frames
+    "shrinking": lambda made, B: any(
+        b < pb and len(s) < b for (_, _, pp, pb), (_, s, p, b)
+        in zip(made, made[1:]) if pp == p == "staged"),
+}
+
+
+@pytest.mark.parametrize("mix,chunk,case", [
+    ("mixed", 4, "non_contiguous"),
+    ("mixed", 6, "non_contiguous"),
+    ("sparse", 7, "non_contiguous"),
+    ("sparse", 16, "non_contiguous"),
+    ("mixed", 7, "partial"),
+    ("mixed", 16, "partial"),
+    ("dense", 16, "partial"),
+    ("dense", 7, "shrinking"),
+    ("mixed", 16, "shrinking"),
+])
+def test_run_clips_host_batch_matches_per_frame(
+        exec_bank, thresholds, monkeypatch, mix, chunk, case):
+    """``run_clips`` over several clips, with DETECT's full-frame batch
+    taken from the chunk's rows or gathered into the staging buffer
+    that persists across chunks and clips: tracks, counters and every
+    frame's detections are bit-identical to the per-frame path."""
+    bank, clips, res, _ = exec_bank
+    params = _params(bank, res, thresholds[mix], chunk_size=chunk,
+                     tracker="recurrent")
+    made = _full_frame_batches(monkeypatch)
+    reference, ref, got = _per_frame_dets(monkeypatch)
+    results, _ = run_clips(bank, params, clips)
+    assert HOST_BATCH_CASES[case](made, chunk), made
+    for clip, r in zip(clips, results):
+        _assert_same(reference(bank, params, clip), r)
+        want, have = ref[clip.clip_id], got[clip.clip_id]
+        assert len(want) == len(have)
+        for a, b in zip(want, have):
+            np.testing.assert_array_equal(a, b)
+
+
+def test_run_clips_allocates_one_host_batch(exec_bank, thresholds):
+    """With tracing on, every full-frame ``detect.upload`` names its
+    path, both paths occur, and the whole ``run_clips`` call allocates
+    one staging buffer of ``next_bucket(B)`` frames, on the first
+    staged chunk, and no other host batch (whole chunks go up as
+    decoded)."""
+    from repro.core.detector import next_bucket
+    from repro.obs.trace import TRACER
+    bank, clips, res, _ = exec_bank
+    B = 4
+    params = _params(bank, res, thresholds["mixed"], chunk_size=B)
+    TRACER.enable()
+    TRACER.clear()
+    try:
+        run_clips(bank, params, clips)
+        spans = TRACER.snapshot()
+    finally:
+        TRACER.disable()
+        TRACER.clear()
+    W, H = params.det_res
+    full = [s for s in spans
+            if s.name == "detect.upload" and "path" in (s.args or {})]
+    assert {s.args["path"] for s in full} == {"direct", "staged"}
+    assert all("alloc_bytes" in s.args for s in full)
+    allocs = [s.args["alloc_bytes"] for s in spans
+              if s.args and s.args.get("alloc_bytes")]
+    assert allocs == [next_bucket(B) * H * W * 3 * 4]
+    first = next(s for s in full if s.args["path"] == "staged")
+    assert first.args["alloc_bytes"] == allocs[0]
+
+
+def test_staging_buffer_per_thread():
+    """One buffer per draining thread, allocated once and reused: two
+    threads draining runs of one executor never share it."""
+    import threading
+    from repro.core.executor import _Staging
+    staging = _Staging()
+    a = staging.buffer((4, 2, 3, 3))
+    assert a.shape == (4, 2, 3, 3) and a.dtype == np.float32
+    assert staging.buffer((4, 2, 3, 3)) is a
+    other = []
+    t = threading.Thread(target=lambda: other.append(
+        staging.buffer((4, 2, 3, 3))))
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive() and other[0] is not a
+    assert other[0].shape == a.shape
+
+
 def test_executor_mesh_sharded_upload(exec_bank):
     """Chunk uploads through LogicalRules mesh sharding (batch axis on
     the data axis) stay bit-identical."""
